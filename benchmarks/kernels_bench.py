@@ -1,7 +1,8 @@
 """Pallas-kernel micro-benchmarks vs their XLA reference paths.
 
-CAVEAT recorded in EXPERIMENTS.md: this container is CPU-only, so kernels
-run in interpret mode — wall times here are NOT TPU numbers. What IS
+Kernels run where ``kernels.ops.resolve_interpret`` puts them: in the
+Pallas interpreter on the CPU backend, compiled on a TPU. Wall times from
+a CPU run time the interpreter and are NOT TPU numbers. What IS
 meaningful on CPU: the HBM-traffic model (flash attention's O(S·d) vs the
 reference's O(S²) materialization), which we report as derived bytes.
 """
@@ -62,7 +63,7 @@ def bench():
     # dt_pack
     src = jax.random.normal(key, (4096, 64), jnp.float32)
     t_p = _time(lambda s: ops._dtp.dt_pack(s, 16), src)
-    rows.append(("dt_pack/4096x16of64", t_p * 1e6, f"{4096*16*4/t_p/1e6:.0f} MB/s interpret-mode"))
+    rows.append(("dt_pack/4096x16of64", t_p * 1e6, f"{4096*16*4/t_p/1e6:.0f} MB/s on {jax.default_backend()}"))
     return rows
 
 

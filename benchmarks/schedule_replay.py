@@ -43,6 +43,7 @@ from repro.core.enqueue import OffloadWindow
 from repro.core.progress import ProgressEngine
 from repro.core.schedule import Schedule
 from repro.core.streams import StreamPool, stream_comm_create
+from repro.launch.mesh import make_mesh
 from repro.optim.grad_overlap import build_buckets, bucketed_all_reduce_host
 from repro.parallel.pipeline import gpipe_forward_host
 
@@ -59,7 +60,7 @@ def _median_us(samples) -> float:
 def bench_pipeline(steps: int, n_micro: int, mb: int, d: int, layers: int):
     eng = ProgressEngine()
     pool = StreamPool()
-    mesh = jax.make_mesh((1,), ("pipe",))
+    mesh = make_mesh((1,), ("pipe",))
     offload = pool.create(info={"type": "tpu_stream"}, name="sched-pipe")
     comm = stream_comm_create(mesh, ("pipe",), offload)
     Ws = jax.random.normal(jax.random.key(0), (1, layers, d, d)) * 0.3
@@ -119,7 +120,7 @@ def _stage(sp, x):
 def bench_grads(steps: int, leaf_shapes, bucket_bytes: int, n_comms: int):
     eng = ProgressEngine()
     pool = StreamPool()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     comms = [
         stream_comm_create(mesh, ("data",), pool.create(name=f"sched-gb{i}"))
         for i in range(n_comms)

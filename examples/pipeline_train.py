@@ -15,7 +15,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.pipeline import gpipe_forward, split_stages
-from repro.core.threadcomm import shard_map
+from repro.launch.mesh import make_mesh
 
 N_STAGES, LAYERS, D, MB, N_MICRO, VOCAB = 4, 8, 64, 4, 4, 512
 
@@ -38,7 +38,7 @@ def stage_fn(stage_params, x):
 
 
 def main():
-    mesh = jax.make_mesh((N_STAGES, 2), ("pipe", "dp"))
+    mesh = make_mesh((N_STAGES, 2), ("pipe", "dp"))
     params = init(jax.random.key(0))
 
     def loss_fn(params, tokens):
@@ -57,7 +57,7 @@ def main():
             l = jnp.where(rank == N_STAGES - 1, -ll.mean(), 0.0)
             return jax.lax.psum(l, "pipe")
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(P("pipe"), P()), out_specs=P(), check_vma=False
         )(params["stages"], tokens)
 

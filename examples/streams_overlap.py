@@ -18,11 +18,12 @@ from jax.sharding import PartitionSpec as P
 import repro.core as C
 from repro.core.collectives import all_reduce, multi_stream_all_reduce
 from repro.core.hierarchical import hierarchical_all_reduce, hierarchical_collective_bytes
+from repro.launch.mesh import make_mesh
 from repro.optim.grad_overlap import build_buckets, bucketed_all_reduce
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     tc = C.threadcomm_init(mesh, ("pod", "data"))
     print(f"[mesh] {dict(mesh.shape)} — threadcomm size {tc.size()}")
 

@@ -108,7 +108,7 @@ def _require_offload(comm: StreamComm) -> None:
 # ----------------------------------------------------------------------
 
 
-def pack_send(x, datatype: dtt.Datatype, count: int = 1, *, interpret: bool = True):
+def pack_send(x, datatype: dtt.Datatype, count: int = 1, *, interpret: Optional[bool] = None):
     """Materialize the packed payload of a ``(buffer, Datatype)`` send.
 
     ``x`` is the flat(tenable) element buffer the datatype addresses.
@@ -122,7 +122,8 @@ def pack_send(x, datatype: dtt.Datatype, count: int = 1, *, interpret: bool = Tr
 
     Traced buffers (inside ``shard_map``/``jit``) can only take the
     device path; an irregular layout there raises with a pointer at the
-    host path rather than silently breaking tracing.
+    host path rather than silently breaking tracing. ``interpret``
+    follows :func:`repro.kernels.ops.resolve_interpret`.
     """
     from repro.kernels import ops  # deferred: kernels import jax pallas
 
@@ -271,8 +272,6 @@ def _mapped_ring_send(mesh, axes: Tuple[str, ...], dest_offset: int):
     """Jitted SPMD ring-send program for host-issued (windowed) enqueues:
     one token-sealed ppermute over ``axes[0]``. Cached per (mesh, axes,
     offset) so steady-state windowed sends hit the jit cache."""
-    from repro.core.threadcomm import shard_map  # deferred: import order
-
     axis = axes[0]
     n = mesh.shape[axis]
     perm = [(i, (i + dest_offset) % n) for i in range(n)]
@@ -282,7 +281,7 @@ def _mapped_ring_send(mesh, axes: Tuple[str, ...], dest_offset: int):
         return lax.ppermute(x_s, axis, perm)[None]
 
     return jax.jit(
-        shard_map(per_shard, mesh=mesh, in_specs=_P(axis), out_specs=_P(axis), check_vma=False)
+        jax.shard_map(per_shard, mesh=mesh, in_specs=_P(axis), out_specs=_P(axis), check_vma=False)
     )
 
 

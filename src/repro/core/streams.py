@@ -63,14 +63,6 @@ __all__ = [
 DEFAULT_NUM_CHANNELS = 64
 
 
-def axis_size(name):
-    """Size of a mapped mesh axis inside a shard_map region, portable
-    across jax versions (``lax.axis_size`` only exists in newer jax)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 @dataclass(frozen=True)
 class MPIXStream:
     """A local serial execution context (thread, host task, device queue)."""
@@ -225,7 +217,7 @@ class StreamComm:
         """Flattened rank inside a shard_map region (traced value)."""
         r = jax.lax.axis_index(self.axes[0])
         for a in self.axes[1:]:
-            r = r * axis_size(a) + jax.lax.axis_index(a)
+            r = r * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return r
 
     def with_axes(self, axes: Sequence[str]) -> "StreamComm":
@@ -276,29 +268,15 @@ def token_join(*tokens):
     return out
 
 
-@jax.custom_jvp
-def _barrier(operands):
-    return jax.lax.optimization_barrier(operands)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    # the barrier is the identity for AD: tangents pass straight through
-    # (older jax has no differentiation rule for optimization_barrier, and
-    # custom_vjp trips shard_map's spec check there)
-    (operands,), (d_operands,) = primals, tangents
-    return _barrier(operands), d_operands
-
-
 def serialize_on(token, *arrays):
     """Tie ``arrays`` to ``token``: none of them may be reordered before the
     op that produced the token. Returns (new_token, arrays).
 
     Uses ``lax.optimization_barrier`` — the XLA-native way to impose
     ordering without data dependence (the TPU analogue of issuing on a
-    serial stream context) — wrapped with an identity VJP so device-ordered
-    sends stay differentiable (pipeline backward = AD transpose of the
-    forward's enqueued ops) on jax versions without a built-in rule.
+    serial stream context). It differentiates as the identity, so
+    device-ordered sends stay differentiable (pipeline backward = AD
+    transpose of the forward's enqueued ops).
     """
-    sealed = _barrier((token, *arrays))
+    sealed = jax.lax.optimization_barrier((token, *arrays))
     return sealed[0], sealed[1:]

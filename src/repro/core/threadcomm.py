@@ -46,7 +46,6 @@ rank, exactly the paper's "ranks 0..M-1 live in process 0" numbering.
 
 from __future__ import annotations
 
-import inspect
 import itertools
 import threading
 import time
@@ -60,11 +59,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax <= 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-except ImportError:  # newer jax promoted it to the top level
-    from jax import shard_map as _jax_shard_map
-
 from repro.core import threadcoll
 from repro.core.progress import GeneralizedRequest, ProgressEngine, default_engine
 from repro.core.streams import (
@@ -72,26 +66,10 @@ from repro.core.streams import (
     MPIXStream,
     STREAM_NULL,
     StreamPool,
-    axis_size,
     default_pool,
 )
 
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_jax_shard_map).parameters)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False, **kw):
-    """Version-portable ``shard_map``: older jax spells the replication
-    check ``check_rep``, newer jax ``check_vma`` — translate to whichever
-    the installed version accepts."""
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = check_vma
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 __all__ = [
-    "shard_map",
     "ThreadComm",
     "threadcomm_init",
     "threadcomm_free",
@@ -159,7 +137,7 @@ class ThreadComm:
         """Traced flattened rank; valid inside an active region only."""
         r = lax.axis_index(self.axes[0])
         for a in self.axes[1:]:
-            r = r * axis_size(a) + lax.axis_index(a)
+            r = r * lax.axis_size(a) + lax.axis_index(a)
         return r
 
     @property
@@ -178,7 +156,7 @@ class ThreadComm:
         """``MPIX_Threadcomm_start``/``finish`` bracket: execute ``fn`` as
         per-rank SPMD code over the flattened communicator. ``fn`` may call
         any :mod:`repro.core.collectives` op on comms derived from self."""
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=in_specs,
@@ -1273,7 +1251,7 @@ class HybridThreadComm:
 
             spec = P(mc.axes if len(mc.axes) > 1 else mc.axes[0])
             prog = jax.jit(
-                shard_map(body, mesh=mc.mesh, in_specs=spec, out_specs=P())
+                jax.shard_map(body, mesh=mc.mesh, in_specs=spec, out_specs=P(), check_vma=False)
             )
             _hybrid_mesh_progs[key] = prog
         return prog

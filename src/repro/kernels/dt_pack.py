@@ -17,11 +17,19 @@ Blocking is two-level: the block size is chosen purely by VMEM budget
 blocked grid and the remainder segments run as one tail block. The old
 single-level path shrank the block until it divided nseg, which
 degenerated to block=1 (one grid step per segment) for prime nseg.
+
+The TPU compiler accepts a block whose second-to-last dim is a multiple
+of the dtype's sublane tiling (8 rows of 32-bit, 16 of 16-bit, 32 of
+8-bit) or the array's full extent. So the blocked prefix uses a multiple
+of that tiling, and the tail block spans its whole (sliced) array. The
+last dim of every block is the array's full width, which is always
+accepted.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,9 +50,28 @@ def unpack_kernel(packed_ref, out_ref, *, seg_len):
     out_ref[...] = jnp.concatenate([packed_ref[...], pad], axis=1)
 
 
-def _block_segs(nseg: int, stride: int, itemsize: int, vmem_budget: int = 4 << 20) -> int:
-    per_seg = stride * itemsize
-    return max(1, min(nseg, vmem_budget // max(per_seg, 1)))
+#: Bytes of VMEM that one grid step's blocks may take, input and output
+#: each double-buffered by the pipeline: half of v5e's 16 MiB default
+#: scoped-VMEM limit.
+VMEM_BUDGET = 8 << 20
+
+
+def _sublanes(itemsize: int) -> int:
+    """Rows of one (sublane, 128-lane) VMEM tile for this element size."""
+    return max(8, 32 // itemsize)
+
+
+def _block_segs(nseg: int, stride: int, seg_len: int, itemsize: int) -> int:
+    """Segments per grid step: the whole array when it fits the budget,
+    else the largest multiple of the sublane tiling that does (at least
+    one tile). Rows are counted at their lane-padded VMEM width."""
+    lanes = lambda n: -(-n // 128) * 128
+    per_seg = 2 * (lanes(stride) + lanes(seg_len)) * itemsize
+    fit = VMEM_BUDGET // per_seg
+    if fit >= nseg:
+        return nseg
+    sub = _sublanes(itemsize)
+    return max(sub, fit // sub * sub)
 
 
 def _pack_call(src, seg_len: int, bs: int, interpret: bool):
@@ -60,11 +87,15 @@ def _pack_call(src, seg_len: int, bs: int, interpret: bool):
     )(src)
 
 
-def dt_pack(src, seg_len: int, *, interpret: bool = True):
-    """src (nseg, stride) → (nseg, seg_len): gather strided segments."""
+def dt_pack(src, seg_len: int, *, interpret: Optional[bool] = None):
+    """src (nseg, stride) → (nseg, seg_len): gather strided segments.
+    ``interpret`` follows :func:`repro.kernels.ops.resolve_interpret`."""
+    from repro.kernels.ops import resolve_interpret  # deferred: ops imports this module
+
+    interpret = resolve_interpret(interpret)
     nseg, stride = src.shape
     assert seg_len <= stride
-    bs = _block_segs(nseg, stride, src.dtype.itemsize)
+    bs = _block_segs(nseg, stride, seg_len, src.dtype.itemsize)
     main = (nseg // bs) * bs
     if main == nseg:
         return _pack_call(src, seg_len, bs, interpret)
@@ -88,11 +119,15 @@ def _unpack_call(packed, stride: int, bs: int, interpret: bool):
     )(packed)
 
 
-def dt_unpack(packed, stride: int, *, interpret: bool = True):
-    """packed (nseg, seg_len) → (nseg, stride): scatter back (gaps zeroed)."""
+def dt_unpack(packed, stride: int, *, interpret: Optional[bool] = None):
+    """packed (nseg, seg_len) → (nseg, stride): scatter back (gaps zeroed).
+    ``interpret`` follows :func:`repro.kernels.ops.resolve_interpret`."""
+    from repro.kernels.ops import resolve_interpret  # deferred: ops imports this module
+
+    interpret = resolve_interpret(interpret)
     nseg, seg_len = packed.shape
     assert seg_len <= stride
-    bs = _block_segs(nseg, stride, packed.dtype.itemsize)
+    bs = _block_segs(nseg, stride, seg_len, packed.dtype.itemsize)
     main = (nseg // bs) * bs
     if main == nseg:
         return _unpack_call(packed, stride, bs, interpret)

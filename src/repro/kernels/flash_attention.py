@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,13 +74,21 @@ def flash_attention_kernel(
 
 
 def flash_attention(
-    q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int = 128, interpret: bool = True
+    q,
+    k,
+    v,
+    *,
+    causal: bool = True,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: Optional[bool] = None,
 ):
     """q/k/v (BH, S, d) — pre-expanded heads (see ops.gqa_flash).
 
-    interpret=True runs the kernel body on CPU (validation); pass
-    interpret=False on real TPU.
+    ``interpret`` follows :func:`repro.kernels.ops.resolve_interpret`.
     """
+    from repro.kernels.ops import resolve_interpret  # deferred: ops imports this module
+
     BH, S, d = q.shape
     block_q = min(block_q, S)
     block_k = min(block_k, S)
@@ -105,5 +114,5 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

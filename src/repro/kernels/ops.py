@@ -2,13 +2,19 @@
 
 These are the integration points the model zoo calls (flash attention for
 GQA layers, chunked WKV for RWKV-6, dt_pack for the checkpoint/comm
-buffer path). ``interpret`` defaults to True because this container is
-CPU-only; on TPU pass interpret=False (same kernels, real lowering).
+buffer path).
+
+The kernel backend is decided here and nowhere else
+(:func:`resolve_interpret`): a kernel runs in the Pallas interpreter only
+when JAX's default backend is the CPU, and is compiled everywhere else.
+Every ``interpret`` argument in :mod:`repro.kernels` defaults to None,
+meaning "ask :func:`resolve_interpret`".
 """
 
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +24,34 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import rwkv6_scan as _wkv
 from repro.core import datatype as dt
 
-__all__ = ["gqa_flash_attention", "wkv6", "pack_datatype", "unpack_datatype"]
+__all__ = [
+    "resolve_interpret",
+    "gqa_flash_attention",
+    "wkv6",
+    "pack_datatype",
+    "unpack_datatype",
+]
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The one rule for running a Pallas kernel: ``None`` interprets on the
+    CPU backend and compiles on any other. ``False`` always compiles (a
+    test may lower for a described TPU from a CPU process). ``True`` off
+    the CPU is refused: an accelerator must never silently run the
+    interpreter."""
+    backend = jax.default_backend()
+    if interpret is None:
+        return backend == "cpu"
+    if interpret and backend != "cpu":
+        raise ValueError(
+            f"interpret=True on the {backend!r} backend: Pallas kernels are "
+            "compiled on accelerators (pass interpret=None)"
+        )
+    return bool(interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret", "block_q", "block_k"))
-def gqa_flash_attention(q, k, v, causal=True, interpret=True, block_q=128, block_k=128):
+def gqa_flash_attention(q, k, v, causal=True, interpret=None, block_q=128, block_k=128):
     """q (B,S,nq,hd); k/v (B,S,nkv,hd) → (B,S,nq,hd). GQA via KV repeat at
     the head-folding level (no HBM copy on TPU: it lowers to a broadcast
     in the BlockSpec index map domain)."""
@@ -39,7 +68,7 @@ def gqa_flash_attention(q, k, v, causal=True, interpret=True, block_q=128, block
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(w, r, k, v, bonus, state0, chunk=64, interpret=True):
+def wkv6(w, r, k, v, bonus, state0, chunk=64, interpret=None):
     return _wkv.wkv6_chunked(w, r, k, v, bonus, state0, chunk=chunk, interpret=interpret)
 
 
@@ -70,7 +99,9 @@ def _kernel_info(dtype_descr: dt.Datatype, info, itemsize: int):
     return nseg, seg_bytes, stride_bytes, disp
 
 
-def pack_datatype(buf_flat, dtype_descr: dt.Datatype, *, info=None, interpret: bool = True):
+def pack_datatype(
+    buf_flat, dtype_descr: dt.Datatype, *, info=None, interpret: Optional[bool] = None
+):
     """Pack a uniform-strided datatype from a flat element buffer using the
     Pallas kernel; raises on irregular layouts (host iovec path covers
     those — see core.datatype.pack).  ``info`` accepts a precomputed
@@ -91,7 +122,12 @@ def pack_datatype(buf_flat, dtype_descr: dt.Datatype, *, info=None, interpret: b
 
 
 def unpack_datatype(
-    packed_flat, dtype_descr: dt.Datatype, out_len: int, *, info=None, interpret: bool = True
+    packed_flat,
+    dtype_descr: dt.Datatype,
+    out_len: int,
+    *,
+    info=None,
+    interpret: Optional[bool] = None,
 ):
     """Inverse of pack_datatype into a zeroed flat buffer of out_len elems."""
     nseg, seg_bytes, stride_bytes, disp = _kernel_info(

@@ -23,6 +23,7 @@ ref.py holds the per-token oracle; tests sweep shapes/dtypes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,14 +79,19 @@ def wkv6_kernel(w_ref, r_ref, k_ref, v_ref, u_ref, o_ref, s_out_ref, state_scr, 
         s_out_ref[0] = new_state
 
 
-def wkv6_chunked(w, r, k, v, bonus, state0, *, chunk: int = 64, interpret: bool = True):
+def wkv6_chunked(
+    w, r, k, v, bonus, state0, *, chunk: int = 64, interpret: Optional[bool] = None
+):
     """w/r/k/v (B, S, H, hs) fp32; bonus (H, hs); state0 (B, H, hs, hs).
 
     Returns (y (B, S, H, hs) fp32, state (B, H, hs, hs)). Initial state is
     added outside the kernel (cheap) so the kernel scratch starts at zero:
     y += (r ⊙ e^{cw_prev + chunk offsets}) @ state0 — handled by folding
-    state0 via a pre-pass below for exactness.
+    state0 via a pre-pass below for exactness. ``interpret`` follows
+    :func:`repro.kernels.ops.resolve_interpret`.
     """
+    from repro.kernels.ops import resolve_interpret  # deferred: ops imports this module
+
     B, S, H, hs = w.shape
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
@@ -113,7 +119,7 @@ def wkv6_chunked(w, r, k, v, bonus, state0, *, chunk: int = 64, interpret: bool 
             jax.ShapeDtypeStruct((B * H, hs, hs), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(wf, rf, kf, vf, uf.reshape(B * H, 1, hs))
 
     # fold the initial state in exactly: the kernel computed with S_0 = 0;

@@ -115,7 +115,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False, verbose: boo
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    xla_cost = rl.xla_cost_analysis(compiled)
+    xla_cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = rl.collective_bytes(hlo)
     mflops = rl.model_step_flops(cfg, shape)

@@ -1,4 +1,9 @@
-"""Production mesh construction.
+"""Mesh construction.
+
+Every mesh in the repo comes from :func:`make_mesh`, which gives each
+axis ``AxisType.Auto``: GSPMD propagates shardings through the program,
+as ``shard_map`` regions and the ``train_shardings`` rules expect.
+``jax.make_mesh`` alone makes ``Explicit`` axes on the installed JAX.
 
 Single pod: (16, 16) = ("data", "model") — 256 v5e chips.
 Multi-pod:  (2, 16, 16) = ("pod", "data", "model") — 512 chips; the "pod"
@@ -6,30 +11,36 @@ axis crosses the slower inter-pod links, so DP spans ("pod","data") and
 the hierarchical collectives in repro.core.hierarchical split legs
 accordingly.
 
-A FUNCTION, not a module-level constant: importing this module never
+FUNCTIONS, not module-level constants: importing this module never
 touches jax device state (dryrun.py must set XLA_FLAGS first).
 """
 
 from __future__ import annotations
 
-import jax
+from typing import Optional, Sequence
 
-__all__ = ["make_production_mesh", "make_host_mesh", "HW"]
+import jax
+from jax.sharding import AxisType
+
+__all__ = ["make_mesh", "make_production_mesh", "HW"]
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with every axis ``Auto``; ``devices`` defaults to
+    all of ``jax.devices()``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class HW:
-    """TPU v5e per-chip roofline constants (targets; container is CPU)."""
+    """TPU v5e per-chip roofline constants (Google Cloud, "TPU v5e")."""
 
     PEAK_FLOPS_BF16 = 197e12  # FLOP/s
     HBM_BW = 819e9  # B/s

@@ -26,18 +26,8 @@ __all__ = [
     "RooflineTerms",
     "roofline_terms",
     "fmt_seconds",
-    "xla_cost_analysis",
 ]
 
-
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: older releases
-    return a one-element list of per-program dicts, newer ones the dict
-    itself. Always returns the dict (empty if XLA reports nothing)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost or {})
 
 _DTYPE_BYTES = {
     "pred": 1,

@@ -1,6 +1,9 @@
 """Serving driver: continuous-batching engine + request-level stats.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --requests 8
+
+runs the published widths (an accelerator's job); ``--smoke`` runs the
+reduced preset, which is what the CPU can serve.
 """
 
 from __future__ import annotations
@@ -12,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving.engine import ServeEngine
 
@@ -19,13 +23,14 @@ from repro.serving.engine import ServeEngine
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true", help="reduced preset (CPU sizes)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params = api.init_params(cfg, jax.random.key(0))
     eng = ServeEngine(cfg, params, max_batch=args.max_batch, max_len=args.max_len)

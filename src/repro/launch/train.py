@@ -11,6 +11,9 @@ stream gets its own progress thread (spin-up at save, spin-down after);
 failures trigger the elastic re-mesh plan + restore-from-latest.
 
 Run: PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b --steps 20
+
+at the published widths (an accelerator's job); add ``--smoke`` for the
+reduced preset the CPU can train.
 """
 
 from __future__ import annotations
@@ -204,13 +207,14 @@ class Trainer:
         if grad_overlap == "windowed":
             from repro.core.enqueue import OffloadWindow
             from repro.core.streams import stream_comm_create
+            from repro.launch.mesh import make_mesh
             from repro.optim.grad_overlap import build_buckets
 
             self._grad_fn = jax.jit(make_grad_step(cfg))
             self._update_fn = jax.jit(
                 lambda g, o, p: adamw_update(opt_cfg, g, o, p)
             )
-            mesh = jax.make_mesh((1,), ("data",))
+            mesh = make_mesh((1,), ("data",))
             self._grad_comms = [
                 stream_comm_create(mesh, ("data",), stream_create(name=f"grad{i}"))
                 for i in range(max(1, grad_comms))
@@ -266,6 +270,7 @@ class Trainer:
             **hb_kwargs,
         )
         self.history = []
+        self.step_times: list = []  # seconds per step, loss read back included
         self.last_progress_stats: Optional[dict] = None
 
     def maybe_restore(self):
@@ -544,6 +549,7 @@ class Trainer:
                 # noted here and recovered at the next step boundary
                 self.heartbeat.check()
                 self.history.append(loss)
+                self.step_times.append(dt_step)
                 if step % log_every == 0:
                     print(f"[trainer] step {step} loss {loss:.4f} ({dt_step*1e3:.0f} ms)")
                 if self.ckpt and step > 0 and step % self.ckpt_every == 0:
@@ -586,12 +592,14 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true", help="reduced preset (CPU sizes)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     tr = Trainer(
         cfg,

@@ -199,11 +199,10 @@ def gqa_attention_full(
         ):
             # Pallas blocked attention: O(S·d) HBM traffic instead of the
             # einsum path's O(S²) logit materialization (see EXPERIMENTS
-            # §Perf kernel notes). interpret=True on CPU, native on TPU.
+            # §Perf kernel notes). kernels.ops picks the backend.
             from repro.kernels.ops import gqa_flash_attention
 
-            interpret = jax.default_backend() != "tpu"
-            out = gqa_flash_attention(q, k, v, causal=True, interpret=interpret)
+            out = gqa_flash_attention(q, k, v, causal=True)
             out = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"].astype(cfg.cdtype)
             return out, (k, v)
         if causal:

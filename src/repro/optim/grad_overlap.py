@@ -138,8 +138,6 @@ def _bucket_program(comm: StreamComm, start: int, n: int, scatter: bool):
     the two is inherited from running the *same* executable. Memoized:
     a fresh closure per call would defeat jit's trace cache and re-trace
     every bucket on every eager step."""
-    from repro.core.threadcomm import shard_map  # deferred: import order
-
     key = (comm, start, n, bool(scatter))
     cached = _bucket_programs.get(key)
     if cached is not None:
@@ -156,7 +154,7 @@ def _bucket_program(comm: StreamComm, start: int, n: int, scatter: bool):
 
     out_spec = P(axis) if scatter else P()
     prog = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P(), out_specs=out_spec, check_vma=False)
+        jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=out_spec, check_vma=False)
     )
     _bucket_programs[key] = prog
     return prog
@@ -174,8 +172,6 @@ def _bucket_ag_program(comm: StreamComm, n: int):
     replicated result. ``RS ∘ AG`` on the same comm equals the bucket's
     all-reduce (the Rabenseifner identity), so the split pair stays
     interchangeable with :func:`_bucket_program`'s psum."""
-    from repro.core.threadcomm import shard_map  # deferred: import order
-
     key = (comm, n, "ag")
     cached = _bucket_programs.get(key)
     if cached is not None:
@@ -187,7 +183,7 @@ def _bucket_ag_program(comm: StreamComm, n: int):
         return z
 
     prog = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
+        jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     )
     _bucket_programs[key] = prog
     return prog

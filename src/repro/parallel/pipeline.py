@@ -34,8 +34,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.enqueue import OffloadWindow, _poll_dispatched, dispatch_enqueue
-from repro.core.streams import StreamComm, axis_size, new_token, serialize_on
-from repro.core.threadcomm import shard_map
+from repro.core.streams import StreamComm, new_token, serialize_on
 
 __all__ = ["gpipe_forward", "gpipe_forward_host", "pipeline_loss_fn", "split_stages"]
 
@@ -66,7 +65,7 @@ def gpipe_forward(stage_fn: Callable, stage_params, x_micro, axis_name: str):
     x_micro: (n_micro, mb, S, d) — microbatch activations fed to stage 0.
     Returns (n_micro, mb, S, d) stage-(P-1) outputs (valid on last rank).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     ticks = n_micro + n_stages - 1
@@ -113,7 +112,7 @@ def _tick_program(stage_fn: Callable, mesh, axis: str, n_stages: int):
         return nxt[None], y[None]
 
     prog = jax.jit(
-        shard_map(
+        jax.shard_map(
             tick,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P()),
@@ -335,12 +334,12 @@ def pipeline_loss_fn(
             outs = gpipe_forward(stage_fn, stage_params, xm, pipe_axis)
             outs = outs.reshape(B, *outs.shape[2:])
             rank = lax.axis_index(pipe_axis)
-            n_stages = axis_size(pipe_axis)
+            n_stages = lax.axis_size(pipe_axis)
             l = head_loss_fn(params["head"], outs, tokens)
             l = jnp.where(rank == n_stages - 1, l, 0.0)
             return lax.psum(l, pipe_axis)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(pipe_axis), P()),

@@ -19,7 +19,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.core as C
-from repro.core.threadcomm import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as col
 from repro.core import enqueue as enq
 from repro.core.hierarchical import flat_all_reduce, hierarchical_all_reduce
@@ -35,7 +35,7 @@ def check(name, cond):
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     tc = C.threadcomm_init(mesh, ("pod", "data"))
     x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
     shard_sum = np.asarray(x).reshape(8, 1, 16).sum(0)
@@ -172,7 +172,7 @@ def main():
         y, _ = jax.lax.scan(lyr, x, stage_params)
         return y
 
-    pmesh = jax.make_mesh((4, 2), ("pipe", "dp"))
+    pmesh = make_mesh((4, 2), ("pipe", "dp"))
 
     def loss_pipe(Ws_stacked, xs):
         def inner(sp, xm):
@@ -183,7 +183,7 @@ def main():
             l = jnp.where(rank == P_STAGES - 1, l, 0.0)
             return jax.lax.psum(l, "pipe")
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=pmesh, in_specs=(P("pipe"), P()), out_specs=P(), check_vma=False
         )(split_stages(Ws_stacked, P_STAGES), xs)
 
@@ -209,7 +209,7 @@ def main():
     from repro.core.progress import ProgressEngine
     from repro.parallel.pipeline import gpipe_forward_host
 
-    pipe_mesh = jax.make_mesh((4,), ("pipe",))
+    pipe_mesh = make_mesh((4,), ("pipe",))
     off_pipe = C.stream_create(info={"type": "tpu_stream"}, name="pipe-off")
     pipe_comm = C.stream_comm_create(pipe_mesh, ("pipe",), off_pipe)
     outs_w, win = gpipe_forward_host(
@@ -232,7 +232,7 @@ def main():
     from repro.parallel import sharding as shd
 
     cfg = get_config("qwen1.5-0.5b", smoke=True).replace(grad_accum=2)
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     params = api.init_params(cfg, jax.random.key(0))
     opt_cfg = AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=10)
     opt = adamw_init(opt_cfg, params)

@@ -124,6 +124,7 @@ def test_wkv6_model_integration():
     (32, 32, 32, jnp.float32),  # dense: seg == stride
     (61, 8, 16, jnp.float32),   # odd nseg, fits one block
     (300, 16, 4096, jnp.float32),  # nseg % vmem-block != 0: main+tail path
+    (1000, 1000, 3000, jnp.float32),  # sublane-rounded blocks + tail
 ])
 def test_dt_pack_sweep(nseg, seg, stride, dtype):
     src = jax.random.normal(KEY, (nseg, stride), jnp.float32).astype(dtype)
@@ -131,6 +132,37 @@ def test_dt_pack_sweep(nseg, seg, stride, dtype):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref.pack_ref(src, seg)))
     back = dtp.dt_unpack(out, stride, interpret=True)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(ref.unpack_ref(out, stride)))
+
+
+@pytest.mark.parametrize("nseg,seg,stride,dtype", [
+    (1000, 1000, 3000, jnp.float32),
+    (300, 16, 4096, jnp.float32),
+    (4096, 512, 1024, jnp.bfloat16),
+    (2048, 100, 256, jnp.uint8),
+    (100000, 64, 128, jnp.uint8),
+    (5, 1 << 20, 1 << 20, jnp.float32),  # wider than the budget: one tile
+])
+def test_dt_pack_blocks_fit_tiling_and_vmem(nseg, seg, stride, dtype):
+    """The TPU compiler takes a block of whole sublane tiles or the full
+    extent; the double-buffered in+out blocks stay within the budget
+    unless one tile alone exceeds it."""
+    item = jnp.dtype(dtype).itemsize
+    bs = dtp._block_segs(nseg, stride, seg, item)
+    sub = dtp._sublanes(item)
+    assert bs == nseg or bs % sub == 0
+    lanes = lambda n: -(-n // 128) * 128
+    if bs > sub:
+        assert 2 * bs * (lanes(stride) + lanes(seg)) * item <= dtp.VMEM_BUDGET
+
+
+def test_resolve_interpret_is_the_one_backend_rule(monkeypatch):
+    assert ops.resolve_interpret(None) is True  # this process runs on the CPU
+    assert ops.resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False
+    assert ops.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True"):
+        ops.resolve_interpret(True)
 
 
 def test_pack_datatype_matches_host_engine():

@@ -27,7 +27,7 @@ def test_xla_cost_analysis_counts_loop_bodies_once():
 
     def flops(n):
         ws = jax.ShapeDtypeStruct((n, 128, 128), jnp.float32)
-        return rl.xla_cost_analysis(jax.jit(f).lower(x, ws).compile())["flops"]
+        return jax.jit(f).lower(x, ws).compile().cost_analysis()["flops"]
 
     assert flops(2) == flops(32)
 
@@ -52,7 +52,7 @@ def test_jaxpr_cost_exact_for_plain_matmul():
     b = jax.ShapeDtypeStruct((512, 128), jnp.float32)
     c = step_cost(lambda a, b: a @ b, a, b)
     assert c.flops == 2 * 256 * 512 * 128
-    xla = rl.xla_cost_analysis(jax.jit(lambda a, b: a @ b).lower(a, b).compile())["flops"]
+    xla = jax.jit(lambda a, b: a @ b).lower(a, b).compile().cost_analysis()["flops"]
     assert c.flops == xla
 
 
