@@ -1,0 +1,1 @@
+"""On-chip benchmark of the serving and training paths (see ``run.py``)."""

@@ -1,0 +1,13 @@
+"""Model step (api.loss_fn through Trainer): forward and backward
+operations per token from shapes, no recomputation, times train tokens
+per second, over the chip's bf16 peak."""
+
+from bench import counts
+
+
+def read(run):
+    if run.peaks is None or run.kind != "train":
+        return None
+    t = run.train
+    flops = counts.train_step_flops(run.spec, t["batch"], t["seq"]) * t["steps"]
+    return flops / (run.seconds * run.peaks["bf16_flop_per_s"]) * 100.0
