@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
@@ -271,7 +272,6 @@ class Trainer:
         )
         self.history = []
         self.step_times: list = []  # seconds per step, loss read back included
-        self.last_progress_stats: Optional[dict] = None
 
     def maybe_restore(self):
         if self.ckpt is None:
@@ -483,6 +483,70 @@ class Trainer:
         )
         return {"loss": loss, **om}
 
+    def _run_step(self, step: int, log_every: int) -> None:
+        """One step of :meth:`run`, each phase in its own ``repro.train.*``
+        profiler span (about a microsecond each when no profile is taken)."""
+        # detect → replan → reshard → resume: a failure the heartbeat
+        # detector noted since the last step boundary is recovered HERE,
+        # then the loop keeps stepping on the shrunken mesh (history stays
+        # continuous)
+        failed = self._take_failures()
+        if failed:
+            with TraceAnnotation("repro.train.recover"):
+                self.recover(failed)
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.train.prefetch"):
+            self.pipeline.prefetch(step + 1)
+        with TraceAnnotation("repro.train.get_batch"):
+            host_batch = self.pipeline.get_batch(step)
+        with TraceAnnotation("repro.train.h2d"):
+            batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+            if "img_embeds" in batch:
+                batch["img_embeds"] = batch["img_embeds"].astype(self.cfg.cdtype)
+            if "enc_frames" in batch:
+                batch["enc_frames"] = batch["enc_frames"].astype(self.cfg.cdtype)
+        with TraceAnnotation("repro.train.dispatch"):
+            if self.grad_overlap == "windowed":
+                metrics = self._windowed_step(batch)
+            else:
+                self.params, self.opt_state, metrics = self.step_fn(
+                    self.params, self.opt_state, batch
+                )
+        with TraceAnnotation("repro.train.readback"):
+            loss = float(metrics["loss"])
+        dt_step = time.perf_counter() - t0
+        with TraceAnnotation("repro.train.bookkeeping"):
+            durations = {}
+            for r in list(self.ranks):
+                d = dt_step
+                if self.fault_injector is not None:
+                    # straggle faults report extra step seconds — the
+                    # monitor sees the slowdown without anyone sleeping
+                    d += self.fault_injector.stage_delay(r)
+                durations[r] = d
+            self.straggler.record_step(durations)
+            advice = self.straggler.check()
+            if advice:
+                # rebalance advice is enacted on the live pipeline; evict
+                # escalation stays with the heartbeat/recover path (a
+                # straggler is slow, not dead)
+                self._apply_straggler_advice(advice)
+            for r in list(self.ranks):
+                self.heartbeat.record(r)
+            if self.hb_clock is not None and self.hb_tick > 0:
+                self.hb_clock.advance(self.hb_tick)
+            # one synchronous detector visit per step: a rank whose
+            # heartbeats stopped (dead, or suppressed by injection) is
+            # noted here and recovered at the next step boundary
+            self.heartbeat.check()
+            self.history.append(loss)
+            self.step_times.append(dt_step)
+            if step % log_every == 0:
+                print(f"[trainer] step {step} loss {loss:.4f} ({dt_step*1e3:.0f} ms)")
+        if self.ckpt and step > 0 and step % self.ckpt_every == 0:
+            with TraceAnnotation("repro.train.ckpt"):
+                self.ckpt.save_async(step, {"params": self.params, "opt": self.opt_state})
+
     def run(self, steps: int, log_every: int = 10):
         # background progress only where async work is actually in flight —
         # the paper's control knob (ext. 6), now driven by stats(): the
@@ -501,59 +565,8 @@ class Trainer:
         try:
             self.pipeline.prefetch(self.start_step)
             for step in range(self.start_step, self.start_step + steps):
-                # detect → replan → reshard → resume: a failure the
-                # heartbeat detector noted since the last step boundary is
-                # recovered HERE, then the loop keeps stepping on the
-                # shrunken mesh (history stays continuous)
-                failed = self._take_failures()
-                if failed:
-                    self.recover(failed)
-                t0 = time.perf_counter()
-                self.pipeline.prefetch(step + 1)
-                batch = {
-                    k: jnp.asarray(v) for k, v in self.pipeline.get_batch(step).items()
-                }
-                if "img_embeds" in batch:
-                    batch["img_embeds"] = batch["img_embeds"].astype(self.cfg.cdtype)
-                if "enc_frames" in batch:
-                    batch["enc_frames"] = batch["enc_frames"].astype(self.cfg.cdtype)
-                if self.grad_overlap == "windowed":
-                    metrics = self._windowed_step(batch)
-                else:
-                    self.params, self.opt_state, metrics = self.step_fn(
-                        self.params, self.opt_state, batch
-                    )
-                loss = float(metrics["loss"])
-                dt_step = time.perf_counter() - t0
-                durations = {}
-                for r in list(self.ranks):
-                    d = dt_step
-                    if self.fault_injector is not None:
-                        # straggle faults report extra step seconds — the
-                        # monitor sees the slowdown without anyone sleeping
-                        d += self.fault_injector.stage_delay(r)
-                    durations[r] = d
-                self.straggler.record_step(durations)
-                advice = self.straggler.check()
-                if advice:
-                    # rebalance advice is enacted on the live pipeline;
-                    # evict escalation stays with the heartbeat/recover
-                    # path (a straggler is slow, not dead)
-                    self._apply_straggler_advice(advice)
-                for r in list(self.ranks):
-                    self.heartbeat.record(r)
-                if self.hb_clock is not None and self.hb_tick > 0:
-                    self.hb_clock.advance(self.hb_tick)
-                # one synchronous detector visit per step: a rank whose
-                # heartbeats stopped (dead, or suppressed by injection) is
-                # noted here and recovered at the next step boundary
-                self.heartbeat.check()
-                self.history.append(loss)
-                self.step_times.append(dt_step)
-                if step % log_every == 0:
-                    print(f"[trainer] step {step} loss {loss:.4f} ({dt_step*1e3:.0f} ms)")
-                if self.ckpt and step > 0 and step % self.ckpt_every == 0:
-                    self.ckpt.save_async(step, {"params": self.params, "opt": self.opt_state})
+                with StepTraceAnnotation("repro.train.step", step_num=step):
+                    self._run_step(step, log_every)
             if self.ckpt:
                 final = self.start_step + steps - 1
                 self.ckpt.save_async(final, {"params": self.params, "opt": self.opt_state})
@@ -568,7 +581,6 @@ class Trainer:
                 self.tuner.stop()  # demotes every autotuner-placed thread
             self.engine.stop_all()
             st = self.engine.stats()
-            self.last_progress_stats = st
             print(
                 f"[trainer] progress engine: {st['completions']} completions, "
                 f"{st['polls']} polls, {st['lock_waits']} lock waits, "

@@ -31,6 +31,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.threadcomm import HostThreadComm
 from repro.serving.engine import Request, ServeEngine
 
@@ -60,6 +62,11 @@ class Completion:
         return len(self.req.out_tokens)
 
     @property
+    def t_first_token(self) -> Optional[float]:
+        """When the engine read back the request's first token (its prefill)."""
+        return self.req.t_first_token
+
+    @property
     def queue_wait_s(self) -> float:
         return self.t_submit - self.t_arrival
 
@@ -77,13 +84,15 @@ class AdmissionFrontEnd:
     """Continuous-batching admission loop around a :class:`ServeEngine`.
 
     The engine must carry a ``progress_engine`` — completion streaming is
-    ``engine.wait_any`` over the per-request generalized requests.
+    ``engine.wait_any`` over the per-request generalized requests. The
+    default ``clock`` is the one the engine stamps ``Request.t_submit``
+    and ``t_first_token`` with, so a completion's stamps compare.
     """
 
     def __init__(
         self,
         engine: ServeEngine,
-        clock: Callable[[], float] = time.monotonic,
+        clock: Callable[[], float] = time.perf_counter,
         idle_park_s: float = 0.02,
         name: str = "serve-admit",
     ):
@@ -143,21 +152,25 @@ class AdmissionFrontEnd:
                 # 1) drain the ingest mailbox into the engine queue
                 while not eof and r0.iprobe(src=1) is not None:
                     msg = r0.recv(src=1)
+                    t_recv = self.clock()
                     if msg[0] == "eof":
                         eof = True
                         break
                     _, t_arr, off = msg
-                    try:
-                        req = eng.submit(
-                            off["prompt"],
-                            off.get("max_new_tokens", 16),
-                            off.get("eos_id", -1),
-                        )
-                    except ValueError as e:
-                        self.rejected.append(
-                            {"offer": off, "error": str(e), "t_arrival": t_arr}
-                        )
-                        continue
+                    with TraceAnnotation("repro.admit.submit") as span:
+                        try:
+                            req = eng.submit(
+                                off["prompt"],
+                                off.get("max_new_tokens", 16),
+                                off.get("eos_id", -1),
+                            )
+                        except ValueError as e:
+                            self.rejected.append(
+                                {"offer": off, "error": str(e), "t_arrival": t_arr}
+                            )
+                            continue
+                        # mailbox_ms: the loader's stamp to rank 0's recv
+                        span.set_metadata(rid=req.rid, mailbox_ms=(t_recv - t_arr) * 1e3)
                     meta[req.rid] = (t_arr, self.clock())
                     pending.append(req)
 
@@ -167,16 +180,17 @@ class AdmissionFrontEnd:
                     self.steps += 1
 
                 # 3) stream completions as they finish (completion order)
-                while pending:
-                    done = eng.wait_any(pending, timeout=0.0)
-                    if done is None:
-                        break
-                    pending.remove(done)
-                    t_arr, t_sub = meta.pop(done.rid)
-                    c = Completion(done, t_arr, t_sub, self.clock())
-                    completions.append(c)
-                    if on_complete is not None:
-                        on_complete(c)
+                with TraceAnnotation("repro.admit.complete"):
+                    while pending:
+                        done = eng.wait_any(pending, timeout=0.0)
+                        if done is None:
+                            break
+                        pending.remove(done)
+                        t_arr, t_sub = meta.pop(done.rid)
+                        c = Completion(done, t_arr, t_sub, self.clock())
+                        completions.append(c)
+                        if on_complete is not None:
+                            on_complete(c)
 
                 if eof and not pending and eng._idle():
                     break
@@ -184,7 +198,8 @@ class AdmissionFrontEnd:
                     # nothing to decode and the loader is mid-gap: park on
                     # the ingest mailbox instead of spinning
                     try:
-                        r0.probe(src=1, timeout=self.idle_park_s)
+                        with TraceAnnotation("repro.admit.park"):
+                            r0.probe(src=1, timeout=self.idle_park_s)
                     except TimeoutError:
                         pass  # re-check the loop (offers may still be coming)
             else:

@@ -18,12 +18,14 @@ from __future__ import annotations
 import collections
 import itertools
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.enqueue import _poll_dispatched
 from repro.core.progress import GeneralizedRequest, ProgressEngine
@@ -44,6 +46,8 @@ class Request:
     out_tokens: List[int] = field(default_factory=list)
     done: bool = False
     grequest: Optional[GeneralizedRequest] = None  # set when a progress engine is attached
+    t_submit: float = 0.0  # time.perf_counter at submit()
+    t_first_token: Optional[float] = None  # time.perf_counter when the prefill token was read back
 
 
 class ServeEngine:
@@ -78,6 +82,7 @@ class ServeEngine:
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         self.queue: Deque[Request] = collections.deque()
         self._rid = itertools.count()
+        self.steps = 0  # step() calls: the step_num of the repro.serve.step span
         self._decode = jax.jit(lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos))
         self._prefill = jax.jit(
             lambda p, b: api.prefill(cfg, p, b, max_len=max_len), static_argnames=()
@@ -98,7 +103,7 @@ class ServeEngine:
             )
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        req = Request(next(self._rid), prompt, max_new_tokens, eos_id)
+        req = Request(next(self._rid), prompt, max_new_tokens, eos_id, t_submit=time.perf_counter())
         if self.progress_engine is not None:
             # completion handle: externally completed by step() at EOS — no
             # poll_fn, so a blocked wait_all parks on the CV instead of
@@ -147,8 +152,13 @@ class ServeEngine:
         ``max_new_tokens=1`` and eos-on-first-token requests decode one
         extra step and emit one extra token. Returns ``(done, cache1)``;
         a done request must not occupy a slot."""
-        last_logits, cache1 = self._prefill(self.params, {"tokens": req.prompt[None, :]})
-        tok = int(np.argmax(np.asarray(last_logits[0])))
+        queued_ms = (time.perf_counter() - req.t_submit) * 1e3
+        S = int(req.prompt.shape[0])
+        with TraceAnnotation("repro.serve.prefill", rid=req.rid, S=S, queued_ms=queued_ms):
+            last_logits, cache1 = self._prefill(self.params, {"tokens": req.prompt[None, :]})
+            with TraceAnnotation("repro.serve.prefill.readback"):
+                tok = int(np.argmax(np.asarray(last_logits[0])))
+        req.t_first_token = time.perf_counter()
         req.out_tokens.append(tok)
         if tok == req.eos_id or len(req.out_tokens) >= req.max_new_tokens:
             req.done = True
@@ -168,14 +178,16 @@ class ServeEngine:
                     break
                 # finished at admission (EOS/limit on the prefill token):
                 # the slot stays free for the next queued request
-            # splice the single-row cache into this slot (batch dim = axis 1
-            # for stacked caches, axis 0 inside per-layer leaves of dim B..)
-            self.cache = jax.tree.map(
-                lambda full, one: _splice(full, one, slot), self.cache, cache1
-            )
-            self.slot_req[slot] = req
-            self.pos[slot] = req.prompt.shape[0]
-            self.cur_tok[slot] = req.out_tokens[-1]
+            self._place(slot, req, cache1, req.prompt.shape[0])
+
+    def _place(self, slot: int, req: Request, cache1, pos: int) -> None:
+        """Splice ``req``'s single-row cache into ``slot`` (batch dim = axis
+        1 of the stacked cache leaves) and resume its decode at ``pos``."""
+        with TraceAnnotation("repro.serve.splice", rid=req.rid, slot=slot):
+            self.cache = jax.tree.map(lambda full, one: _splice(full, one, slot), self.cache, cache1)
+        self.slot_req[slot] = req
+        self.pos[slot] = pos
+        self.cur_tok[slot] = req.out_tokens[-1]
 
     # -- decode loop ----------------------------------------------------------
     def _decode_active(self):
@@ -184,13 +196,17 @@ class ServeEngine:
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return active, None
-        if self.step_schedule is not None:
-            logits = self._decode_scheduled()
-        else:
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self.cur_tok), jnp.asarray(self.pos)
-            )
-        return active, np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with TraceAnnotation("repro.serve.decode", active=len(active)):
+            with TraceAnnotation("repro.serve.decode.dispatch"):
+                if self.step_schedule is not None:
+                    logits = self._decode_scheduled()
+                else:
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, jnp.asarray(self.cur_tok), jnp.asarray(self.pos)
+                    )
+            with TraceAnnotation("repro.serve.decode.readback"):
+                next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        return active, next_tok
 
     def _decode_scheduled(self):
         """The recorded steady-state decode. First active step records and
@@ -261,10 +277,13 @@ class ServeEngine:
 
     def step(self) -> int:
         """Admit + decode one token for all active slots. Returns #active."""
-        self._admit()
-        active, next_tok = self._decode_active()
-        for i in active:
-            self._advance_slot(i, int(next_tok[i]))
+        with StepTraceAnnotation("repro.serve.step", step_num=self.steps):
+            self.steps += 1
+            self._admit()
+            active, next_tok = self._decode_active()
+            with TraceAnnotation("repro.serve.advance"):
+                for i in active:
+                    self._advance_slot(i, int(next_tok[i]))
         return len(active)
 
     def run_until_done(self, max_steps: int = 10_000) -> None:
@@ -605,10 +624,7 @@ class PagedServeEngine(ServeEngine):
             # at the expense of younger parked requests and retry once
             self._make_room(sum(1 for p in self.kv.page_table(req.rid) if p is None))
             cache1 = self.kv.gather(req.rid)
-        self.cache = jax.tree.map(lambda full, one: _splice(full, one, slot), self.cache, cache1)
-        self.slot_req[slot] = req
-        self.pos[slot] = self.kv.length(req.rid)
-        self.cur_tok[slot] = req.out_tokens[-1]
+        self._place(slot, req, cache1, self.kv.length(req.rid))
 
     def _prefill_paged(self, req: Request) -> bool:
         """Prefill + write the prompt span into fresh pages. Returns False
@@ -648,13 +664,7 @@ class PagedServeEngine(ServeEngine):
                 req = self.queue.popleft()
                 if not self._prefill_paged(req):
                     continue  # done at admission; slot stays free
-                cache1 = self.kv.gather(req.rid)
-                self.cache = jax.tree.map(
-                    lambda full, one: _splice(full, one, slot), self.cache, cache1
-                )
-                self.slot_req[slot] = req
-                self.pos[slot] = req.prompt.shape[0]
-                self.cur_tok[slot] = req.out_tokens[-1]
+                self._place(slot, req, self.kv.gather(req.rid), req.prompt.shape[0])
                 admitted = True
                 break
             if not admitted and not self.parked:

@@ -408,7 +408,7 @@ def test_admission_front_end_streams_and_rejects(params):
     # the invalid offer bounced at submit() instead of killing the loop
     assert len(fe.rejected) == 1
     assert "does not fit max_len" in fe.rejected[0]["error"]
-    assert all(c.t_arrival <= c.t_submit <= c.t_done for c in out)
+    assert all(c.t_arrival <= c.t_submit <= c.t_first_token <= c.t_done for c in out)
     assert all(len(c.req.out_tokens) >= 1 for c in out)
     pe.stop_all()
 
