@@ -124,19 +124,6 @@ def causal_mask(S: int, T: int, offset: int = 0, window: int = 0):
     return m[None, None, None]  # (1,1,1,S,T)
 
 
-def decode_mask(T: int, pos, ring: bool = False):
-    """Mask for one-token decode against a cache of physical length T.
-
-    Full cache: slots <= pos are valid. Ring cache: all slots valid once
-    pos+1 >= T, else slots <= pos.
-    """
-    kpos = jnp.arange(T)[None, :]
-    m = kpos <= pos[:, None]
-    if ring:
-        m = m | (pos[:, None] + 1 >= T)
-    return m[:, None, None, None]  # (B,1,1,1,T)
-
-
 def gqa_params(cfg: ModelConfig, key, theta_unused=None):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
@@ -221,31 +208,54 @@ def gqa_attention_full(
 
 
 def gqa_attention_decode(
-    cfg: ModelConfig, p, x, cache_kv, pos, window: int = 0, theta: float = 10_000.0, use_rope: bool = True
+    cfg: ModelConfig,
+    p,
+    x,
+    cache_kv,
+    layer,
+    pos,
+    window: int = 0,
+    theta: float = 10_000.0,
+    use_rope: bool = True,
 ):
-    """One-token decode. x (B,1,d); cache_kv = (K,V) of (B,T,nkv,hd); pos
-    (B,) absolute position of the new token. Ring-buffer update when
-    window > 0 (T == window)."""
+    """One-token decode against a layer-stacked cache, written in place.
+
+    x (B,1,d); cache_kv = (K, V), each (L, B, T, nkv*hd): every kv head of
+    a position side by side on the minor axis, so the leaf keeps its
+    natural layout on the chip (hd alone is narrower than a lane tile).
+    ``layer`` indexes axis 0; pos (B,) is the new token's absolute position.
+    Only the B new rows are written, at [layer, b, slot_b] (slot = pos, or
+    pos % T on a ring when window > 0, T == window), and attention reads
+    K[layer] / V[layer] in the flat layout: the head split is a 0/1
+    indicator E (nkv*hd, nkv) applied to q and to the read-out, never a
+    reshape of the cache. Callers carry the stack through their layer
+    scan, so no layer of it is sliced out and written back.
+    """
     B = x.shape[0]
     hd = cfg.resolved_head_dim
+    nkv = cfg.n_kv_heads
+    G = cfg.n_heads // nkv
     q, k_new, v_new = gqa_qkv(cfg, p, x, pos[:, None], theta, use_rope=use_rope)
     K, V = cache_kv
-    T = K.shape[1]
-    slot = jnp.where(window > 0, pos % jnp.maximum(T, 1), pos)
+    T = K.shape[2]
+    slot = pos % T if window > 0 else pos
     bidx = jnp.arange(B)
-    K = K.at[bidx, slot].set(k_new[:, 0].astype(K.dtype))
-    V = V.at[bidx, slot].set(v_new[:, 0].astype(V.dtype))
-    if window > 0:
-        mask = jnp.where(
-            (pos + 1 >= T)[:, None],
-            jnp.ones((B, T), bool),
-            jnp.arange(T)[None, :] <= pos[:, None],
-        )[:, None, None, None]
-    else:
-        mask = (jnp.arange(T)[None, :] <= pos[:, None])[:, None, None, None]
-    out = attend(q, K.astype(cfg.cdtype), V.astype(cfg.cdtype), mask, 1.0 / math.sqrt(hd))
-    out = out.reshape(B, 1, cfg.n_heads * hd) @ p["wo"].astype(cfg.cdtype)
-    return out, (K, V)
+    K = K.at[layer, bidx, slot].set(k_new.reshape(B, nkv * hd).astype(K.dtype))
+    V = V.at[layer, bidx, slot].set(v_new.reshape(B, nkv * hd).astype(V.dtype))
+    # group member g of every kv head side by side: qg[b, g] lines up with a cache row
+    qg = q.reshape(B, nkv, G, hd).transpose(0, 2, 1, 3).reshape(B, G, nkv * hd)
+    E = (jnp.arange(nkv * hd)[:, None] // hd == jnp.arange(nkv)[None, :]).astype(cfg.cdtype)
+    qe = qg[..., None] * E  # (B, G, nkv*hd, nkv): exact, one nonzero per row
+    scores = jnp.einsum("btd,bgdn->bgnt", K[layer], qe, preferred_element_type=jnp.float32)
+    scores = scores * (1.0 / math.sqrt(hd))
+    # slot t holds a position <= pos once written; on a ring every slot is
+    # written (and within the window) from pos = T-1 on, so one test serves both
+    mask = (jnp.arange(T)[None, :] <= pos[:, None])[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(cfg.cdtype)
+    o = jnp.einsum("bgnt,btd->bgnd", probs, V[layer], preferred_element_type=jnp.float32)
+    out = (o * E.T).sum(axis=2).astype(cfg.cdtype)  # each row keeps its own head
+    out = out.reshape(B, G, nkv, hd).transpose(0, 2, 1, 3).reshape(B, 1, cfg.n_heads * hd)
+    return out @ p["wo"].astype(cfg.cdtype), (K, V)
 
 
 # ----------------------------------------------------------------------

@@ -86,14 +86,11 @@ def init_jamba(cfg: ModelConfig, key):
 def init_cache(cfg: ModelConfig, B: int, max_len: int):
     layout = block_layout(cfg)
     nb = n_blocks(cfg)
-    hd = cfg.resolved_head_dim
+    D = cfg.n_kv_heads * cfg.resolved_head_dim  # flat: see gqa_attention_decode
     entries = []
     for mixer, _ in layout:
         if mixer == "attn":
-            one = (
-                jnp.zeros((B, max_len, cfg.n_kv_heads, hd), cfg.cdtype),
-                jnp.zeros((B, max_len, cfg.n_kv_heads, hd), cfg.cdtype),
-            )
+            one = (jnp.zeros((B, max_len, D), cfg.cdtype), jnp.zeros((B, max_len, D), cfg.cdtype))
         else:
             one = mamba_mod.mamba_init_state(cfg, B)
         entries.append(jax.tree.map(lambda a: jnp.broadcast_to(a, (nb,) + a.shape).copy(), one))
@@ -107,6 +104,7 @@ def _apply_position_full(cfg, mixer, ffn, lp, x, positions, st, train: bool = Fa
     h = apply_norm(cfg, lp["ln1"], x)
     if mixer == "attn":
         a, st2 = gqa_attention_full(cfg, lp["mixer"], h, positions, theta=cfg.rope_theta)
+        st2 = tuple(t.reshape(t.shape[:2] + (-1,)) for t in st2)  # the cache's flat leaf
     else:
         a, st2 = mamba_mod.mamba_full(cfg, lp["mixer"], h, st)
     x = x + a
@@ -118,21 +116,24 @@ def _apply_position_full(cfg, mixer, ffn, lp, x, positions, st, train: bool = Fa
     return x + f, aux, st2
 
 
-def _apply_position_decode(cfg, mixer, ffn, lp, x, cache, pos):
+def _apply_position_decode(cfg, mixer, ffn, lp, x, cache, l, pos):
+    """Decode block ``l`` at one period position; ``cache`` is that
+    position's entry stacked over blocks, returned updated."""
     h = apply_norm(cfg, lp["ln1"], x)
     if mixer == "attn":
-        a, cache = gqa_attention_decode(cfg, lp["mixer"], h, cache, pos, theta=cfg.rope_theta)
+        a, cache = gqa_attention_decode(cfg, lp["mixer"], h, cache, l, pos, theta=cfg.rope_theta)
     else:
-        a, cache = mamba_mod.mamba_decode(cfg, lp["mixer"], h, cache)
+        one = jax.tree.map(lambda c: c[l], cache)
+        a, one = mamba_mod.mamba_decode(cfg, lp["mixer"], h, one)
+        cache = jax.tree.map(lambda c, o: c.at[l].set(o), cache, one)
     x = x + a
     h = apply_norm(cfg, lp["ln2"], x)
     f = moe_mod.moe_apply(cfg, lp["ffn"], h)[0] if ffn == "moe" else swiglu(cfg, lp["ffn"], h)
     return x + f, cache
 
 
-def _forward(cfg: ModelConfig, params, tokens, cache=None, pos=None, decode=False, train=False):
+def _forward(cfg: ModelConfig, params, tokens, cache=None, train=False):
     layout = block_layout(cfg)
-    nb = n_blocks(cfg)
     B, S = tokens.shape
     x = embed(cfg, params["embed"], tokens)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -144,16 +145,12 @@ def _forward(cfg: ModelConfig, params, tokens, cache=None, pos=None, decode=Fals
         lps, caches = lps_caches
         new_entries = []
         for (mixer, ffn), lp, cv in zip(layout, lps, caches):
-            if decode:
-                x, cv2 = _apply_position_decode(cfg, mixer, ffn, lp, x, cv, pos)
-                a = jnp.float32(0)
-            else:
-                x, a, cv2 = _apply_position_full(cfg, mixer, ffn, lp, x, positions, cv, train=train)
+            x, a, cv2 = _apply_position_full(cfg, mixer, ffn, lp, x, positions, cv, train=train)
             aux = aux + a
             new_entries.append(cv2)
         return (x, aux), tuple(new_entries)
 
-    wrapped = remat_wrap(cfg, block) if not decode else block
+    wrapped = remat_wrap(cfg, block)
 
     def scan_body(carry, xs):
         return wrapped(xs, carry)
@@ -190,5 +187,21 @@ def jamba_prefill(cfg: ModelConfig, params, batch, max_len=None):
 
 
 def jamba_decode_step(cfg: ModelConfig, params, cache, tokens, pos):
-    logits, _, cache = _forward(cfg, params, tokens[:, None], cache=cache, pos=pos, decode=True)
-    return logits[:, 0], cache
+    """One-token decode; the cache rides in the block scan's carry, so the
+    attention position writes its new token into the stack in place."""
+    layout = block_layout(cfg)
+    x = embed(cfg, params["embed"], tokens[:, None])
+
+    def scan_body(carry, xs):
+        x, caches = carry
+        lps, l = xs
+        new_entries = []
+        for (mixer, ffn), lp, cv in zip(layout, lps, caches):
+            x, cv = _apply_position_decode(cfg, mixer, ffn, lp, x, cv, l, pos)
+            new_entries.append(cv)
+        return (x, tuple(new_entries)), None
+
+    xs = (params["blocks"], jnp.arange(n_blocks(cfg)))
+    (x, cache), _ = lax.scan(scan_body, (x, tuple(cache)), xs)
+    x = apply_norm(cfg, params["final_norm"], x)
+    return logits_out(cfg, params["embed"], x)[:, 0], list(cache)

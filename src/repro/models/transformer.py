@@ -187,12 +187,16 @@ def _apply_layer_full(cfg, spec: LayerSpec, lp, x, positions, train: bool = Fals
     return x + f, aux, seed
 
 
-def _apply_layer_decode(cfg, spec: LayerSpec, lp, x, cache, pos):
+def _apply_layer_decode(cfg, spec: LayerSpec, lp, x, cache, l, pos):
+    """Decode layer ``l`` of a group; ``cache`` is this pattern position's
+    entry stacked over the group's reps, returned updated."""
     h = apply_norm(cfg, lp["ln1"], x)
     if spec.attn == "mla":
-        a, cache = mla_mod.mla_decode(cfg, lp["attn"], h, cache, pos, spec.theta)
+        one = jax.tree.map(lambda c: c[l], cache)
+        a, one = mla_mod.mla_decode(cfg, lp["attn"], h, one, pos, spec.theta)
+        cache = jax.tree.map(lambda c, o: c.at[l].set(o), cache, one)
     else:
-        a, cache = gqa_attention_decode(cfg, lp["attn"], h, cache, pos, window=spec.window, theta=spec.theta)
+        a, cache = gqa_attention_decode(cfg, lp["attn"], h, cache, l, pos, window=spec.window, theta=spec.theta)
     x = x + a
     h = apply_norm(cfg, lp["ln2"], x)
     if spec.moe:
@@ -295,11 +299,8 @@ def _layer_cache_shape(cfg: ModelConfig, spec: LayerSpec, B: int, max_len: int):
             jnp.zeros((B, T, m.kv_lora_rank), cfg.cdtype),
             jnp.zeros((B, T, m.qk_rope_head_dim), cfg.cdtype),
         )
-    hd = cfg.resolved_head_dim
-    return (
-        jnp.zeros((B, T, cfg.n_kv_heads, hd), cfg.cdtype),
-        jnp.zeros((B, T, cfg.n_kv_heads, hd), cfg.cdtype),
-    )
+    D = cfg.n_kv_heads * cfg.resolved_head_dim  # flat: see gqa_attention_decode
+    return jnp.zeros((B, T, D), cfg.cdtype), jnp.zeros((B, T, D), cfg.cdtype)
 
 
 def init_cache(cfg: ModelConfig, B: int, max_len: int):
@@ -327,6 +328,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: Optional[int] = None):
             seed = g_seeds[pi]  # tuple of (reps,B,S,...) arrays
 
             def to_cache(a):
+                a = a.reshape(a.shape[:3] + (-1,))  # GQA (nkv, hd) -> nkv*hd
                 T = min(spec.window, max_len) if spec.window else max_len
                 S_seed = a.shape[2]
                 if S_seed >= T:
@@ -344,23 +346,23 @@ def prefill(cfg: ModelConfig, params, batch, max_len: Optional[int] = None):
 
 def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
     """One-token decode. tokens (B,) int32, pos (B,) absolute positions.
-    Returns (logits (B,vocab), new_cache)."""
+    Returns (logits (B,vocab), new_cache). Each group's cache rides in the
+    layer scan's carry, so every layer writes its new token into the stack
+    in place; only the parameters are scanned over."""
     x = embed(cfg, params["embed"], tokens[:, None])
     new_cache = []
     for (pattern, reps), gp, gc in zip(layer_groups(cfg), params["groups"], cache):
 
-        def block(lps_and_cache, x):
-            lps, caches = lps_and_cache
+        def scan_body(carry, xs):
+            x, caches = carry
+            lps, l = xs
             new_entries = []
             for spec, lp, cv in zip(pattern, lps, caches):
-                x, cv2 = _apply_layer_decode(cfg, spec, lp, x, cv, pos)
-                new_entries.append(cv2)
-            return x, tuple(new_entries)
+                x, cv = _apply_layer_decode(cfg, spec, lp, x, cv, l, pos)
+                new_entries.append(cv)
+            return (x, tuple(new_entries)), None
 
-        def scan_body(x, xs):
-            return block(xs, x)
-
-        x, gc2 = lax.scan(scan_body, x, (gp, gc))
+        (x, gc2), _ = lax.scan(scan_body, (x, gc), (gp, jnp.arange(reps)))
         new_cache.append(gc2)
     x = apply_norm(cfg, params["final_norm"], x)
     logits = logits_out(cfg, params["embed"], x)

@@ -146,13 +146,14 @@ def whisper_loss(cfg: ModelConfig, params, batch):
 
 
 def init_cache(cfg: ModelConfig, B: int, max_len: int):
+    """Self-attention K/V flat, (L, B, T, nkv*hd), written in place by
+    decode (see gqa_attention_decode); cross-attention K/V keep their heads
+    split, (L, B, n_audio_ctx, nkv, hd): prefill writes them once and the
+    full-sequence attention reads them as they are."""
     hd = cfg.resolved_head_dim
     L = cfg.n_layers
-    kv = lambda T: (
-        jnp.zeros((L, B, T, cfg.n_kv_heads, hd), cfg.cdtype),
-        jnp.zeros((L, B, T, cfg.n_kv_heads, hd), cfg.cdtype),
-    )
-    return {"self": kv(max_len), "cross": kv(cfg.n_audio_ctx)}
+    kv = lambda *tail: tuple(jnp.zeros((L, B) + tail, cfg.cdtype) for _ in range(2))
+    return {"self": kv(max_len, cfg.n_kv_heads * hd), "cross": kv(cfg.n_audio_ctx, cfg.n_kv_heads, hd)}
 
 
 def whisper_prefill(cfg: ModelConfig, params, batch, max_len=None):
@@ -166,6 +167,7 @@ def whisper_prefill(cfg: ModelConfig, params, batch, max_len=None):
     (k_self, v_self), (k_x, v_x) = seeds
 
     def pad_to(a, T):
+        a = a.reshape(a.shape[:3] + (-1,))  # the self cache's flat leaf
         if a.shape[2] == T:
             return a
         pad = [(0, 0)] * a.ndim
@@ -191,20 +193,21 @@ def whisper_decode_step(cfg: ModelConfig, params, cache, tokens, pos):
     pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)[:, None, :]
     x = x + pe.astype(cfg.cdtype)
 
-    def body(x, xs):
-        lp, (ks, vs), (kx, vx) = xs
+    def body(carry, xs):
+        x, kv = carry
+        lp, l, (kx, vx) = xs
         h = apply_norm(cfg, lp["ln1"], x)
-        a, (ks, vs) = gqa_attention_decode(cfg, lp["attn"], h, (ks, vs), pos, use_rope=False)
+        a, kv = gqa_attention_decode(cfg, lp["attn"], h, kv, l, pos, use_rope=False)
         x = x + a
         h = apply_norm(cfg, lp["lnx"], x)
         a, _ = gqa_attention_full(cfg, lp["xattn"], h, None, kv_override=(kx, vx))
         x = x + a
         x = x + gelu_mlp(cfg, lp["mlp"], apply_norm(cfg, lp["ln2"], x))
-        return x, (ks, vs)
+        return (x, kv), None
 
-    ks, vs = cache["self"]
-    kx, vx = cache["cross"]
-    x, (ks2, vs2) = lax.scan(body, x, (params["dec_layers"], (ks, vs), (kx, vx)))
+    # the self cache rides in the carry (written in place); cross K/V are read-only
+    xs = (params["dec_layers"], jnp.arange(cfg.n_layers), cache["cross"])
+    (x, self_kv), _ = lax.scan(body, (x, tuple(cache["self"])), xs)
     x = apply_norm(cfg, params["final_norm"], x)
     logits = logits_out(cfg, params["embed"], x)
-    return logits[:, 0], {"self": (ks2, vs2), "cross": cache["cross"]}
+    return logits[:, 0], {"self": self_kv, "cross": cache["cross"]}

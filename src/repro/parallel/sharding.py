@@ -188,7 +188,7 @@ def batch_specs(cfg: ModelConfig, batch_shape, mesh):
 
 
 def cache_specs(cfg: ModelConfig, cache_shape, mesh, seq_axis_model: bool = True):
-    """Decode caches: (..., B, T, heads, hd) → batch over data, T over
+    """Decode caches: (L, B, T, nkv*hd) → batch over data, T over
     model (sequence-parallel decode). Recurrent states (RWKV/Mamba) shard
     their channel/head dim over model instead."""
     dp = dp_axes(mesh)
@@ -213,7 +213,7 @@ def cache_specs(cfg: ModelConfig, cache_shape, mesh, seq_axis_model: bool = True
             spec[1] = dp_if(shape[1])
             spec[-1] = M if _divisible_dim(shape[-1], M, mesh) else None
             return P(*spec)
-        # attention KV / MLA latent: (L,B,T,·[,·])
+        # attention KV / MLA latent: (L,B,T,·), whisper's cross K/V (L,B,T,·,·)
         spec = [None] * nd
         if nd >= 3:
             spec[1] = dp_if(shape[1])

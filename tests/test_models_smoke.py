@@ -21,9 +21,9 @@ from repro.launch.train import make_train_step
 _FAST_ARCHS = {"granite-moe-1b-a400m"}
 
 
-def _arch_params(fast=()):
+def _arch_params(fast=(), *extra):
     return [
-        pytest.param(a, marks=() if a in fast else pytest.mark.slow)
+        pytest.param(a, *extra, marks=() if a in fast else pytest.mark.slow, id=a)
         for a in list_archs()
     ]
 
@@ -66,8 +66,18 @@ def test_smoke_forward_and_train_step(arch):
     assert np.isfinite(float(m["loss"]))
 
 
-@pytest.mark.parametrize("arch", _arch_params(fast=_FAST_ARCHS))
-def test_smoke_decode_matches_full_forward(arch):
+# Every arch prefills 15 tokens and decodes the 16th. The extra cases decode
+# several steps in a row, each checked at its own position: a GQA config with
+# three query heads per kv head, and a windowed ring (gemma3 smoke: window 8)
+# that wraps during decode, so each overwritten slot must drop out of the mask.
+_DECODE_CASES = _arch_params(_FAST_ARCHS, 15) + [
+    pytest.param("internlm2-20b", 10, id="internlm2-20b-gqa3-6steps"),
+    pytest.param("gemma3-4b", 5, id="gemma3-4b-ring-wrap-11steps"),
+]
+
+
+@pytest.mark.parametrize("arch,prompt_len", _DECODE_CASES)
+def test_smoke_decode_matches_full_forward(arch, prompt_len):
     cfg = get_config(arch, smoke=True)
     params = api.init_params(cfg, KEY)
     B, S = 2, 16
@@ -78,33 +88,37 @@ def test_smoke_decode_matches_full_forward(arch):
 
     n_img = cfg.n_img_tokens if cfg.vlm else 0  # positions include the prefix
     pre = dict(batch)
-    pre["tokens"] = toks[:, : S - 1]
-    last, cache = api.prefill(cfg, params, pre, max_len=S + n_img)
-    pos = jnp.full((B,), n_img + S - 1, jnp.int32)
-    dec_logits, _ = api.decode_step(cfg, params, cache, toks[:, S - 1], pos)
+    pre["tokens"] = toks[:, :prompt_len]
+    _, cache = api.prefill(cfg, params, pre, max_len=S + n_img)
+    decode = jax.jit(lambda c, t, pos: api.decode_step(cfg, params, c, t, pos))
+    dec_logits = []
+    for i in range(prompt_len, S):
+        logits, cache = decode(cache, toks[:, i], jnp.full((B,), n_img + i, jnp.int32))
+        dec_logits.append(logits)
 
     full = dict(batch)
     if cfg.encdec:
         from repro.models import whisper as W
 
         enc = W.encode(cfg, params, batch["enc_frames"])
-        ref = W._decode_full(cfg, params, toks, enc)[0][:, -1]
+        ref = W._decode_full(cfg, params, toks, enc)[0]
     elif cfg.family == "ssm_rwkv":
         from repro.models import rwkv6 as R
 
-        ref = R.rwkv_forward(cfg, params, full)[0][:, -1]
+        ref = R.rwkv_forward(cfg, params, full)[0]
     elif cfg.family == "hybrid":
         from repro.models import jamba as J
 
-        ref = J._forward(cfg, params, toks)[0][:, -1]
+        ref = J._forward(cfg, params, toks)[0]
     else:
         from repro.models import transformer as T
 
-        ref = T.lm_forward(cfg, params, full)[0][:, -1]
-    a = np.asarray(dec_logits, np.float32)
-    b = np.asarray(ref, np.float32)
-    rel = np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-9)
-    assert rel < 0.05, f"{arch}: decode/full mismatch rel={rel}"
+        ref = T.lm_forward(cfg, params, full)[0]
+    for i, logits in zip(range(prompt_len, S), dec_logits):
+        a = np.asarray(logits, np.float32)
+        b = np.asarray(ref[:, n_img + i], np.float32)
+        rel = np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-9)
+        assert rel < 0.05, f"{arch}: decode/full mismatch at position {i}: rel={rel}"
 
 
 def test_full_configs_match_assignment_numbers():
