@@ -54,7 +54,8 @@ def sweep(cell: str, rates, seconds: float, seed: int) -> None:
         m["arrivals"]["rate_per_s"] = rate
         inst.forget()
         t_open, t_close, t_end, _ = serving.serve_window(eng, inst, m, s.vocab, seed, seconds, profiler, T0)
-        tracks = list(inst.tracks.values())
+        tracks = sorted(inst.tracks.values(), key=lambda t: t.idx)
+        serving.log_ttft_tail(tracks, inst.decodes, inst.prefills, k=5)
         r = Run(kind="serve", cell=cell, spec=s, peaks=None, t_open=t_open, t_close=t_close, t_end=t_end,
                 requests=tracks)
         v = e2e.values(r)

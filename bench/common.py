@@ -5,6 +5,7 @@ limits or one per-layer metric is a file of its own, found by the name
 that ``BENCHMARK.json`` gives it:
 
     bench/configs/<config>.json     model sizes as run, beside the published ones
+    bench/arch/<arch>.py            what depends on the architecture the config names
     bench/traffic/<mix>.json        parameters of one traffic mix
     bench/limits/<cell>.json        limits of the comparison that decides ``correct``
     bench/metrics/<metric>.py       ``read(run)`` -> number or None

@@ -1,4 +1,7 @@
-"""Operations and bytes from shapes, and the table of peaks.
+"""Operations and bytes from shapes, and the table of peaks. What depends
+on the architecture (the weights a token multiplies by, attention's
+operations, a kernel call's operations and bytes) the spec of
+``bench/arch/<name>.py`` counts; the sums over a run's work are here.
 
 Counts are of what the algorithm needs, not of what the program happens
 to do: a matrix product of an (m, k) by a (k, n) operand is 2·m·k·n
@@ -14,9 +17,9 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
+from typing import Optional
 
 from bench.common import HERE, load_json
-from bench.weights import Spec
 
 BF16 = 2
 
@@ -30,29 +33,16 @@ def peaks(kind: str) -> dict:
     return dict(table["kinds"][kind], source=table["source"])
 
 
-def active_matmul_params(s: Spec) -> int:
-    """Weights one token multiplies by, LM head included, embedding lookup not."""
-    d, hq, hkv = s.d_model, s.n_heads * s.head_dim, s.n_kv_heads * s.head_dim
-    attn = d * hq + 2 * d * hkv + hq * d
-    ffn = s.top_k * 3 * d * s.d_expert + d * s.n_experts if s.moe else 3 * d * s.d_ff
-    return s.n_layers * (attn + ffn) + s.vocab * d
+def prefill_flops(s, S: int) -> int:
+    return 2 * s.active_matmul_params() * S + s.attention_flops(S * (S + 1) // 2)
 
 
-def attention_pairs_flops(s: Spec, pairs: int) -> int:
-    """Scores and values over ``pairs`` query-key pairs, in every layer."""
-    return s.n_layers * s.n_heads * s.head_dim * 4 * pairs
-
-
-def prefill_flops(s: Spec, S: int) -> int:
-    return 2 * active_matmul_params(s) * S + attention_pairs_flops(s, S * (S + 1) // 2)
-
-
-def decode_token_flops(s: Spec, context: int) -> int:
+def decode_token_flops(s, context: int) -> int:
     """One generated token attending to ``context`` positions (itself included)."""
-    return 2 * active_matmul_params(s) + attention_pairs_flops(s, context)
+    return 2 * s.active_matmul_params() + s.attention_flops(context)
 
 
-def train_step_flops(s: Spec, batch: int, seq: int) -> int:
+def train_step_flops(s, batch: int, seq: int) -> int:
     """Forward and backward (three forwards' worth), no recomputation."""
     return 3 * batch * prefill_flops(s, seq)
 
@@ -70,10 +60,8 @@ class KernelCall:
         return "compute" if c >= self.bytes / pk["hbm_bytes_per_s"] else "memory"
 
 
-def flash_attention_call(s: Spec, S: int, batch: int = 1) -> KernelCall:
-    """One causal flash-attention call of one layer: q and o over the query
-    heads, k and v over the key-value heads, bf16."""
-    pairs = S * (S + 1) // 2
-    flops = batch * s.n_heads * s.head_dim * 4 * pairs
-    bytes_ = batch * S * s.head_dim * BF16 * (2 * s.n_heads + 2 * s.n_kv_heads)
-    return KernelCall(flops, bytes_)
+def flash_attention_call(s, S: int, batch: int = 1) -> Optional[KernelCall]:
+    """One causal flash-attention call of one layer, as the architecture
+    counts it; None where its prefill runs no flash kernel."""
+    call = getattr(s, "flash_attention_call", None)
+    return None if call is None else call(S, batch)

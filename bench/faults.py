@@ -9,6 +9,9 @@ built it and before anything runs through it.
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
+
 
 def altered_token(eng):
     """A token altered where it is produced: every decoded token moved by one."""
@@ -22,11 +25,13 @@ def altered_token(eng):
 
 
 def cache_unchanged(eng):
-    """A step that returns its state unchanged: decode never writes the KV cache."""
+    """A step that returns its state unchanged: decode never writes the KV
+    cache. The decode is handed a copy, so one that donates its cache
+    consumes the copy and the unchanged cache is still there to return."""
     decode = eng._decode
 
     def bad(p, c, t, pos):
-        return decode(p, c, t, pos)[0], c
+        return decode(p, jax.tree.map(jnp.copy, c), t, pos)[0], c
 
     eng._decode = bad
 

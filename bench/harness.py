@@ -3,10 +3,12 @@ profiler window, the program's model configuration and the per-layer readers."""
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import shutil
 import tempfile
 import time
+import typing
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -142,13 +144,23 @@ class Profiler:
 def model_config(model: dict, options: Optional[dict] = None):
     """The program's ModelConfig for a configuration file's ``model`` block,
     with a mix's ``model_options`` (serving switches such as attn_impl)."""
-    from repro.models.config import ModelConfig, MoEConfig
+    from repro.models.config import ModelConfig
 
-    m = dict(model)
-    if "moe" in m:
-        m["moe"] = MoEConfig(**m["moe"])
-    m.update(options or {})
-    return ModelConfig(**m)
+    return from_dict(ModelConfig, dict(model, **(options or {})))
+
+
+def from_dict(cls, values: dict):
+    """The dataclass ``cls`` from a dict: a field whose type is a dataclass,
+    or an Optional one, is built from its dict in turn, as the class's own
+    fields say."""
+    hints = typing.get_type_hints(cls)
+    kw = dict(values)
+    for f in dataclasses.fields(cls):
+        if isinstance(kw.get(f.name), dict):
+            sub = [t for t in (hints[f.name], *typing.get_args(hints[f.name])) if dataclasses.is_dataclass(t)]
+            if sub:
+                kw[f.name] = from_dict(sub[0], kw[f.name])
+    return cls(**kw)
 
 
 def per_layer(bench: dict, cell: str, run) -> Dict[str, dict]:

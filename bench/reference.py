@@ -1,13 +1,12 @@
-"""Plain float32 references of the dense and the MoE decoder, written from
-the published architecture and not from the program (nothing here imports
-``repro``).
+"""The parts of the plain float32 references that every architecture
+shares, written from the published conventions and not from the program
+(nothing here imports ``repro``). Each architecture's forward pass is in
+its module under ``bench/arch/``, found from the spec.
 
-One sequence at a time, every matrix product at ``Precision.HIGHEST``,
-softmax and norms in float32, attention as a masked softmax over the whole
-sequence, every expert computed for every token and weighted by the
-renormalised top-k gates. The conventions are the ones the configuration
-files state: RMSNorm scale applied as ``(1 + w)``, rotary embedding on
-split halves (``rotate_half``), tied input and output embedding.
+Every matrix product at ``Precision.HIGHEST``, softmax and norms in
+float32. The conventions are the ones the configuration files state:
+RMSNorm scale applied as ``(1 + w)``, rotary embedding on split halves
+(``rotate_half``).
 
 ``quant=True`` is the control: the same model with both operands of every
 linear layer rounded to float8 e4m3 (per-row scale for activations,
@@ -18,13 +17,13 @@ would be tempted to serve in.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import Spec
+from bench import arch
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
@@ -60,87 +59,25 @@ def rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def attention(s: Spec, lw, h, quant: bool):
-    S = h.shape[0]
-    q = linear(h, lw["wq"], quant)
-    k = linear(h, lw["wk"], quant)
-    v = linear(h, lw["wv"], quant)
-    if s.qkv_bias:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    q = rope(q.reshape(S, s.n_heads, s.head_dim), s.rope_theta)
-    k = rope(k.reshape(S, s.n_kv_heads, s.head_dim), s.rope_theta)
-    v = v.reshape(S, s.n_kv_heads, s.head_dim)
-    g = s.n_heads // s.n_kv_heads
-    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
-    logits = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / np.sqrt(s.head_dim)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    p = jax.nn.softmax(jnp.where(causal[None], logits, -jnp.inf), axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v, precision=HI).reshape(S, s.n_heads * s.head_dim)
-    return linear(o, lw["wo"], quant)
-
-
-def swiglu(lw, h, quant: bool):
-    return linear(jax.nn.silu(linear(h, lw["w_gate"], quant)) * linear(h, lw["w_up"], quant), lw["w_down"], quant)
-
-
-def moe(s: Spec, lw, h, quant: bool):
-    probs = jax.nn.softmax(linear(h, lw["router"], quant), axis=-1)  # (S, E)
-    topv, topi = jax.lax.top_k(probs, s.top_k)
-    gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    dense_gates = jnp.zeros_like(probs).at[jnp.arange(h.shape[0])[:, None], topi].set(gates)
-    wg, wu, wd = lw["we_gate"], lw["we_up"], lw["we_down"]
-    x = h
-    if quant:
-        x, wg, wu, wd = fake_quant(h, -1), fake_quant(wg, 1), fake_quant(wu, 1), fake_quant(wd, 1)
-    a = jax.nn.silu(jnp.einsum("sd,edf->esf", x, wg, precision=HI))
-    a = a * jnp.einsum("sd,edf->esf", x, wu, precision=HI)
-    if quant:
-        a = fake_quant(a, -1)
-    return jnp.einsum("esf,efd,se->sd", a, wd, dense_gates, precision=HI)
-
-
-def layer(s: Spec, quant: bool, x, lw):
-    lw = jax.tree.map(lambda a: a.astype(jnp.float32), lw)
-    x = x + attention(s, lw, rms_norm(x, lw["ln1"], s.eps), quant)
-    h = rms_norm(x, lw["ln2"], s.eps)
-    return x + (moe(s, lw, h, quant) if s.moe else swiglu(lw, h, quant))
-
-
-def layer_weights(w: Dict) -> Dict:
-    return {k: v for k, v in w.items() if k not in ("tok", "final_norm")}
-
-
-def forward(s: Spec, w: Dict, tokens, quant: bool = False, remat: bool = False):
-    """tokens (S,) -> logits (S, vocab), float32."""
-    tok = w["tok"].astype(jnp.float32)
-    x = tok[tokens]
-    body = partial(layer, s, quant)
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(lambda x, lw: (body(x, lw), None), x, layer_weights(w))
-    x = rms_norm(x, w["final_norm"].astype(jnp.float32), s.eps)
-    return linear(x, tok.T, quant)
-
-
 # ----------------------------------------------------------------------
 # serving: how far below the reference's best each served token lies
 # ----------------------------------------------------------------------
 
 
 @partial(jax.jit, static_argnums=(0,))
-def served_gaps(s: Spec, w, tokens, chosen):
+def served_gaps(s, w, tokens, chosen):
     """tokens (L,), chosen (L,): the gap by which ``chosen[i]``'s logit at
     position i lies below the reference's best there."""
-    ref = forward(s, w, tokens)
+    ref = arch.of(s).forward(s, w, tokens)
     return jnp.max(ref, axis=-1) - jnp.take_along_axis(ref, chosen[:, None], axis=-1)[:, 0]
 
 
 @partial(jax.jit, static_argnums=(0,))
-def control_gaps(s: Spec, w, tokens):
+def control_gaps(s, w, tokens):
     """The gap, in the float32 reference, of the token the float8 control
     puts first at each position."""
-    ref = forward(s, w, tokens)
-    ctl = forward(s, w, tokens, quant=True)
+    ref = arch.of(s).forward(s, w, tokens)
+    ctl = arch.of(s).forward(s, w, tokens, quant=True)
     chosen = jnp.argmax(ctl, axis=-1)
     return jnp.max(ref, axis=-1) - jnp.take_along_axis(ref, chosen[:, None], axis=-1)[:, 0]
 
@@ -150,11 +87,11 @@ def control_gaps(s: Spec, w, tokens):
 # ----------------------------------------------------------------------
 
 
-def xent(s: Spec, w, rows, quant: bool = False):
+def xent(s, w, rows, quant: bool = False):
     """Mean next-token cross-entropy over every row of ``rows`` (B, S)."""
 
     def one(t):
-        logits = forward(s, w, t, quant=quant, remat=True)
+        logits = arch.of(s).forward(s, w, t, quant=quant, remat=True)
         lp = jax.nn.log_softmax(logits[:-1], axis=-1)
         return -jnp.mean(jnp.take_along_axis(lp, t[1:, None], axis=-1))
 
@@ -162,7 +99,7 @@ def xent(s: Spec, w, rows, quant: bool = False):
 
 
 @partial(jax.jit, static_argnums=(0, 3))
-def loss_and_grad(s: Spec, w32, rows, quant: bool = False):
+def loss_and_grad(s, w32, rows, quant: bool = False):
     return jax.value_and_grad(lambda p: xent(s, p, rows, quant))(w32)
 
 
@@ -201,7 +138,7 @@ def _adamw(opt_items, grads, m, v, w, n_micro, count, lr):
     return m, v, w, clipped
 
 
-def train_steps(s: Spec, w, batches: Sequence[np.ndarray], opt: dict, micro: int, quant: bool = False,
+def train_steps(s, w, batches: Sequence[np.ndarray], opt: dict, micro: int, quant: bool = False,
                 on_first=lambda clipped: clipped):
     """AdamW over ``batches`` from the weights ``w``, microbatches of
     ``micro`` rows. Returns the losses, ``on_first`` of the first step's

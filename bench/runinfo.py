@@ -9,8 +9,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from bench.weights import Spec
-
 
 @dataclass
 class Track:
@@ -48,7 +46,7 @@ class Decode:
 class Run:
     kind: str  # serve | train
     cell: str
-    spec: Spec
+    spec: Any  # the architecture's Spec (bench/arch/<name>.py)
     peaks: Optional[dict]
     t_open: float
     t_close: float

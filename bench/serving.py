@@ -19,11 +19,10 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import mixes, reference
+from bench import arch, mixes, reference
 from bench.common import log, rng
 from bench.harness import Profiler
 from bench.runinfo import Decode, Prefill, Run, Track
-from bench.weights import Spec
 
 CLOCK = time.perf_counter
 
@@ -197,12 +196,36 @@ def serve_window(eng, inst: Instrumented, mix: dict, vocab: int, seed: int, seco
     late = sorted(lateness)
     log(
         f"[generator] offers={len(late)} late_p50_ms={1e3 * late[len(late) // 2]:.3f} "
-        f"late_p95_ms={1e3 * late[int(0.95 * (len(late) - 1))]:.3f} late_max_ms={1e3 * late[-1]:.3f}"
+        f"late_p95_ms={1e3 * late[int(0.95 * (len(late) - 1))]:.3f} late_max_ms={1e3 * late[-1]:.3f} "
+        f"over_10ms={sum(x > 0.01 for x in late)}"
         if late else "[generator] no offers"
     )
     log(f"[window] t_open->t_close {seconds:.3f} s, drain ended {t_end - t_close:.3f} s after close, "
         f"drained={drained}, rejected={len(front.rejected)}, engine_steps={front.steps}")
     return t_open, t_close, t_end, setup_s
+
+
+def log_ttft_tail(tracks: List[Track], decodes: List[Decode], prefills: List[Prefill], k: int = 8) -> None:
+    """Where the slowest first tokens spent their time: for the ``k`` longest,
+    the generator's lateness, the wait before the prefill (and how much of it
+    was decode steps and other prefills), and the prefill itself."""
+    rows = []
+    for t in tracks:
+        if not t.tokens or t.prefill is None or t.issued is None:
+            continue
+        a, b = t.issued, t.prefill[0]
+        dec = sum(max(0.0, min(d.t1, b) - max(d.t0, a)) for d in decodes)
+        pre = sum(max(0.0, min(p.t1, b) - max(p.t0, a)) for p in prefills)
+        rows.append(((t.tokens[0] - t.due) * 1e3, t, (a - t.due) * 1e3, (b - a) * 1e3, dec * 1e3, pre * 1e3,
+                     (t.prefill[1] - t.prefill[0]) * 1e3))
+    if not rows:
+        return
+    v = sorted(r[0] for r in rows)
+    q = lambda p: v[min(len(v) - 1, int(p * (len(v) - 1)))]  # noqa: E731
+    log(f"[ttft] n={len(v)} p50={q(0.5):.2f} p90={q(0.9):.2f} p95={q(0.95):.2f} p99={q(0.99):.2f} max={v[-1]:.2f} ms")
+    for ttft, t, late, wait, dec, pre, own in sorted(rows, key=lambda r: -r[0])[:k]:
+        log(f"[ttft] idx={t.idx} S={t.prompt_len} ttft={ttft:.2f} late={late:.2f} wait={wait:.2f} "
+            f"(decode {dec:.2f}, prefills {pre:.2f}) prefill={own:.2f} ms")
 
 
 def sample_checked(tracks: List[Track], k: int, seed: int) -> List[Track]:
@@ -216,7 +239,7 @@ def sample_checked(tracks: List[Track], k: int, seed: int) -> List[Track]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def logit_gaps(s: Spec, w, sample: List[Track], pad_to: int, control: bool):
+def logit_gaps(s, w, sample: List[Track], pad_to: int, control: bool):
     """The gap of every served token under the float32 reference, and of the
     float8 control's first choice at the same positions (None without
     ``control``): two flat arrays, one entry per served token compared."""
@@ -253,9 +276,9 @@ def setup(cfg_file: dict, mix: dict, seed: int, seed32: int, shapes, trace: bool
     from bench import harness, weights
 
     cfg = harness.model_config(cfg_file["model"], mix.get("model_options"))
-    s = Spec.from_model(cfg_file["model"])
+    s = arch.spec(cfg_file)
     w = weights.make(s, seed32)
-    params = weights.to_program(s, w)
+    params = arch.of(s).to_program(s, w)
     weights.check_layout(params, jax.eval_shape(lambda k: api.init_params(cfg, k), jax.random.key(0)))
     e = mix["engine"]
     eng = ServeEngine(cfg, params, max_batch=int(e["max_batch"]), max_len=int(e["max_len"]),
@@ -285,9 +308,12 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
     log(f"[compile] backend compiles inside the window and drain: {watch.compiles}")
 
     tracks = sorted(inst.tracks.values(), key=lambda t: t.idx)
+    if mix["loop"] == "open":
+        log_ttft_tail(tracks, inst.decodes, inst.prefills)
     out = Run(
         kind="serve", cell=cell, spec=s, peaks=peaks, t_open=t_open, t_close=t_close, t_end=t_end,
-        max_batch=int(e["max_batch"]), flash_prefill=cfg.attn_impl == "flash",
+        max_batch=int(e["max_batch"]),
+        flash_prefill=cfg.attn_impl == "flash" and hasattr(s, "flash_attention_call"),
         requests=tracks, prefills=list(inst.prefills), decodes=list(inst.decodes),
         trace=profiler.events, trace_span=profiler.span,
     )

@@ -10,6 +10,7 @@ The float8 control, put in the program's place, must come out false too.
 
 import time
 
+import jax
 import pytest
 from conftest import smoke_config, smoke_serve_mix, smoke_train_mix
 
@@ -46,6 +47,29 @@ def test_sound_serving_is_correct(bench_json, cell):
 @pytest.mark.parametrize("fault", sorted(faults.SERVING))
 def test_serving_fault_is_caught(bench_json, cell, fault):
     out = serve(bench_json, cell, faults=faults.SERVING[fault])
+    assert out["correct"] is False, out["checks"]
+    assert out["checks"]["logit_gap"]["value"] > 3 * SERVE_LIMITS["logit_gap"]["limit"]
+
+
+def donating_decode(eng):
+    """The engine's decode, donating its cache as a later program may."""
+    from repro.models import api
+
+    cfg = eng.cfg
+    eng._decode = jax.jit(lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos), donate_argnums=(1,))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cache_unchanged_is_caught_under_a_donating_decode(bench_json, cell):
+    """A decode that donates its cache reads correct, and the fault planted
+    over it reads not correct instead of crashing on a deleted buffer."""
+    assert serve(bench_json, cell, faults=donating_decode)["correct"] is True
+
+    def plant(eng):
+        donating_decode(eng)
+        faults.cache_unchanged(eng)
+
+    out = serve(bench_json, cell, faults=plant)
     assert out["correct"] is False, out["checks"]
     assert out["checks"]["logit_gap"]["value"] > 3 * SERVE_LIMITS["logit_gap"]["limit"]
 

@@ -2,6 +2,7 @@
 timed from when they were due, the generator's lateness, and refusal off
 a TPU."""
 
+import dataclasses
 import json
 import os
 import shutil
@@ -9,9 +10,12 @@ import subprocess
 import sys
 import time
 
+import pytest
 from conftest import ROOT, smoke_config, smoke_serve_mix, smoke_train_mix
 
+from bench import harness
 from bench import run as runmod
+from repro.configs import registry
 
 SERVE_LIMITS = {"unanswered": {"limit": 0}, "wrong_length": {"limit": 0}, "logit_gap": {"limit": 1.0}}
 TRAIN_LIMITS = {"grad_gap": {"limit": 1.0}, "delta_gap": {"limit": 1.0}}
@@ -94,3 +98,11 @@ def test_refused_without_the_program(tmp_path):
     p = subprocess.run([sys.executable, "bench/run.py", "--workload", "qwen1.5-0.5b.chat", "--seed", "1",
                         "--seconds", "1", "--trace", "0"], cwd=tmp_path, env=ENV, capture_output=True, text=True)
     assert p.returncode != 0 and p.stdout == ""
+
+
+@pytest.mark.parametrize("name", registry.list_archs())
+def test_model_config_round_trips(name):
+    """Every nested dataclass of ModelConfig (moe, mla, ssm) is built from
+    its dict, as the config class's own fields say."""
+    c = registry.get_config(name, smoke=True)
+    assert harness.model_config(dataclasses.asdict(c)) == c

@@ -21,42 +21,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, weights
+from bench import arch, reference, weights
 from bench.common import log, rng
 from bench.harness import CompileWatch, Profiler, memory_peak_bytes, model_config
 from bench.runinfo import Run
-from bench.weights import Spec
 
 CLOCK = time.perf_counter
-LAYER_FREE = ("tok", "final_norm")
 
 
-def from_program(s: Spec, tree) -> Dict:
-    """Flatten the program's transformer tree into the benchmark's names."""
-    layer = tree["groups"][0][0]
-    out = {"tok": tree["embed"]["tok"], "final_norm": tree["final_norm"]["w"],
-           "ln1": layer["ln1"]["w"], "ln2": layer["ln2"]["w"]}
-    out.update(layer["attn"])
-    out.update(layer["ffn"])
-    return out
-
-
-@jax.jit
-def unit_norms(flat: Dict):
+@partial(jax.jit, static_argnums=0)
+def unit_norms(s, flat: Dict):
     """L2 norm of each leaf, per layer for the stacked ones: name -> (L,) or (1,)."""
+    free = arch.of(s).layer_free(s)
     out = {}
     for k, v in flat.items():
         v = v.astype(jnp.float32)
-        if k in LAYER_FREE:
+        if k in free:
             out[k] = jnp.sqrt(jnp.sum(v * v))[None]
         else:
             out[k] = jnp.sqrt(jnp.sum(v * v, axis=tuple(range(1, v.ndim))))
     return out
 
 
-@jax.jit
-def change_norms(after: Dict, before: Dict):
-    return unit_norms({k: after[k].astype(jnp.float32) - before[k].astype(jnp.float32) for k in after})
+@partial(jax.jit, static_argnums=0)
+def change_norms(s, after: Dict, before: Dict):
+    return unit_norms(s, {k: after[k].astype(jnp.float32) - before[k].astype(jnp.float32) for k in after})
 
 
 def host(tree) -> Dict[str, np.ndarray]:
@@ -81,12 +70,12 @@ def kept_units(g_ref: Dict[str, np.ndarray], rule: float = 1e-3) -> Dict[str, np
     return {k: v >= rule * med for k, v in g_ref.items()}
 
 
-def compare(s: Spec, opt: dict, init, batches, micro: int, prog: dict, quant: bool = False) -> dict:
+def compare(s, opt: dict, init, batches, micro: int, prog: dict, quant: bool = False) -> dict:
     """Run the reference (or the float8 control) over ``batches`` from
     ``init`` and return the three gaps against the program's readings."""
     losses, g_ref, after = reference.train_steps(s, init, batches, opt, micro, quant=quant,
-                                                 on_first=lambda g: host(unit_norms(g)))
-    d_ref = host(change_norms(after, {k: jnp.asarray(v) for k, v in init.items()}))
+                                                 on_first=lambda g: host(unit_norms(s, g)))
+    d_ref = host(change_norms(s, after, {k: jnp.asarray(v) for k, v in init.items()}))
     del after
     keep = kept_units(g_ref)
     dropped = sorted(f"{k}[{i}]" for k, m in keep.items() for i in np.flatnonzero(~m))
@@ -113,7 +102,8 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
 
     quiet = contextlib.redirect_stdout(sys.stderr)  # the Trainer prints; stdout keeps the result alone
     cfg = model_config(cfg_file["model"])
-    s = Spec.from_model(cfg_file["model"])
+    s = arch.spec(cfg_file)
+    A = arch.of(s)
     B, S = int(mix["batch"]), int(mix["seq"])
     opt = dict(mix["optimizer"])
     opt_cfg = AdamWConfig(**opt)
@@ -124,7 +114,7 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
     tr.params = tr.opt_state = None
     gc.collect()
     w = weights.make(s, seed32)
-    params = weights.to_program(s, w)
+    params = A.to_program(s, w)
     weights.check_layout(params, jax.eval_shape(lambda k: api.init_params(cfg, k), jax.random.key(0)))
     init = jax.device_get(w)
     tr.params = params
@@ -153,10 +143,11 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
 
     with quiet:
         tr.run(1, log_every=1 << 30)
-        grad = host(unit_norms({k: v / (1 - opt["b1"]) for k, v in from_program(s, tr.opt_state["m"]).items()}))
+        grad = host(unit_norms(s, {k: v / (1 - opt["b1"]) for k, v in A.from_program(s, tr.opt_state["m"]).items()}))
         tr.start_step = 1
         tr.run(n_check - 1, log_every=1 << 30)
-    delta = host(change_norms(from_program(s, tr.opt_state["master"]), {k: jnp.asarray(v) for k, v in init.items()}))
+    delta = host(change_norms(s, A.from_program(s, tr.opt_state["master"]),
+                              {k: jnp.asarray(v) for k, v in init.items()}))
     prog = {"losses": list(tr.history[:n_check]), "grad": grad, "delta": delta}
 
     step_s = float(np.mean(tr.step_times[1:n_check])) if n_check > 1 else float(tr.step_times[0])
@@ -196,15 +187,15 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
     return out, readings, n_steps, 0, setup_s
 
 
-def control_readings(s: Spec, opt: dict, init, batches, micro: int) -> dict:
+def control_readings(s, opt: dict, init, batches, micro: int) -> dict:
     """The control in the program's place: the float8 reference's losses,
     first gradient and change, compared with the float32 reference."""
     losses, grad, after = reference.train_steps(s, init, batches, opt, micro, quant=True,
-                                                on_first=lambda g: host(unit_norms(g)))
+                                                on_first=lambda g: host(unit_norms(s, g)))
     ctl = {
         "losses": [float(x) for x in losses],
         "grad": grad,
-        "delta": host(change_norms(after, {k: jnp.asarray(v) for k, v in init.items()})),
+        "delta": host(change_norms(s, after, {k: jnp.asarray(v) for k, v in init.items()})),
     }
     del after
     got = compare(s, opt, init, batches, micro, ctl)
