@@ -1,7 +1,7 @@
 """Model step (api.prefill): operations of each window request's prefill,
 active parameters only, over its host time (synchronous, so the device
-work is inside) times the chip's bf16 peak. Bounds the flash kernel's
-share: a prefill without the kernel still reads here."""
+work is inside) times the bf16 peak of every chip of the cell. Bounds the
+flash kernel's share: a prefill without the kernel still reads here."""
 
 from bench import counts
 
@@ -14,4 +14,4 @@ def read(run):
         return None
     flops = sum(counts.prefill_flops(run.spec, t.prompt_len) for t in done)
     secs = sum(t.prefill[1] - t.prefill[0] for t in done)
-    return flops / (secs * run.peaks["bf16_flop_per_s"]) * 100.0
+    return flops / (secs * run.n_chips * run.peaks["bf16_flop_per_s"]) * 100.0

@@ -1,6 +1,6 @@
 """Model step (api.loss_fn through Trainer): forward and backward
 operations per token from shapes, no recomputation, times train tokens
-per second, over the chip's bf16 peak."""
+per second, over the bf16 peak of every chip of the cell."""
 
 from bench import counts
 
@@ -10,4 +10,4 @@ def read(run):
         return None
     t = run.train
     flops = counts.train_step_flops(run.spec, t["batch"], t["seq"]) * t["steps"]
-    return flops / (run.seconds * run.peaks["bf16_flop_per_s"]) * 100.0
+    return flops / (run.seconds * run.n_chips * run.peaks["bf16_flop_per_s"]) * 100.0

@@ -60,6 +60,7 @@ class Run:
     trace_span: Optional[Tuple[float, float]] = None  # host clock of the traced window
     train: Dict[str, Any] = field(default_factory=dict)
     memory_peak_bytes: Optional[int] = None
+    n_chips: int = 1  # the chips the cell runs on; a utilization is over all of them
 
     @property
     def seconds(self) -> float:

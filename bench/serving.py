@@ -315,7 +315,7 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
         max_batch=int(e["max_batch"]),
         flash_prefill=cfg.attn_impl == "flash" and hasattr(s, "flash_attention_call"),
         requests=tracks, prefills=list(inst.prefills), decodes=list(inst.decodes),
-        trace=profiler.events, trace_span=profiler.span,
+        trace=profiler.events, trace_span=profiler.span, n_chips=n_chips,
     )
     out.memory_peak_bytes = harness.memory_peak_bytes(n_chips)
 

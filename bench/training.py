@@ -166,7 +166,7 @@ def run(cell: str, cfg_file: dict, mix: dict, seed: int, seconds: float, trace: 
     log(f"[window] {n_steps} steps in {t_close - t_open:.3f} s (planned {seconds} s from a "
         f"{step_s * 1e3:.3f} ms set-up step); backend compiles inside: {watch.compiles}")
     out = Run(kind="train", cell=cell, spec=s, peaks=peaks, t_open=t_open, t_close=t_close,
-              trace=profiler.events, trace_span=profiler.span)
+              trace=profiler.events, trace_span=profiler.span, n_chips=n_chips)
     out.train = {"steps": n_steps, "tokens_per_step": B * S, "batch": B, "seq": S,
                  "step_times": list(tr.step_times[n_check:])}
     out.memory_peak_bytes = memory_peak_bytes(n_chips)
